@@ -31,7 +31,7 @@ from repro.problems.load_balancing import (LoadBalanceProblem, ShardWorkload,
 from repro.problems.traffic_engineering import (TrafficProblem,
                                                 k_shortest_paths,
                                                 make_demands, make_topology)
-from .common import emit, save_json
+from .common import emit, save_json, use_compile_cache
 
 CHURN_LEVELS = (0.05, 0.2, 0.5)
 
@@ -207,4 +207,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

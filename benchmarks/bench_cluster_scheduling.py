@@ -18,7 +18,7 @@ from repro.core import ExecConfig, SolveConfig, pop
 from repro.problems.cluster_scheduling import (GavelProblem,
                                                gandiva_heuristic,
                                                make_cluster_workload)
-from .common import Timer, emit, save_json
+from .common import Timer, emit, save_json, use_compile_cache
 
 SOLVER_KW = dict(max_iters=12_000, tol_primal=1e-4, tol_gap=1e-4)
 
@@ -74,4 +74,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -12,7 +12,7 @@ import numpy as np
 
 from repro.problems.load_balancing import (LoadBalanceProblem, estore_greedy,
                                            make_shard_workload)
-from .common import Timer, emit, save_json
+from .common import Timer, emit, save_json, use_compile_cache
 
 SOLVER_KW = dict(max_iters=12_000, tol_primal=1e-4, tol_gap=1e-4)
 
@@ -67,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -19,7 +19,7 @@ from repro.core import SolveConfig
 from repro.domains import (greedy_placement, make_placement_instance,
                            place_experts)
 from repro.domains.moe_placement import _evaluate
-from .common import Timer, emit, save_json
+from .common import Timer, emit, save_json, use_compile_cache
 
 
 def run(n_experts: int = 512, n_devices: int = 16, ks=(4, 8),
@@ -65,6 +65,7 @@ def run(n_experts: int = 512, n_devices: int = 16, ks=(4, 8),
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()

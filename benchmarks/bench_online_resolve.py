@@ -31,7 +31,7 @@ import numpy as np
 from repro.core import ExecConfig, SolveConfig, pop
 from repro.problems.cluster_scheduling import GavelProblem, make_cluster_workload
 from repro.problems.load_balancing import LoadBalanceProblem, make_shard_workload
-from .common import emit, save_json
+from .common import emit, save_json, use_compile_cache
 
 
 def run_cluster(n_jobs: int = 256, k: int = 8, perturb: float = 0.03,
@@ -129,4 +129,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -38,7 +38,7 @@ from scipy.optimize import linprog
 from repro.core import (ExecConfig, LinearProgram, SolveConfig,
                         backends as backends_mod, pdhg, pop)
 from repro.problems.cluster_scheduling import GavelProblem, make_cluster_workload
-from .common import Timer, emit, save_json
+from .common import Timer, emit, save_json, use_compile_cache
 
 DEFAULT_BACKENDS = ("vmap", "chunked_vmap", "shard_map")
 DEFAULT_KS = (1, 2, 4, 8, 16, 32)
@@ -347,4 +347,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

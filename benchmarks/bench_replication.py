@@ -16,7 +16,7 @@ from repro.problems.traffic_engineering import (TrafficProblem,
                                                 k_shortest_paths,
                                                 make_demands, make_topology)
 from .bench_traffic_engineering import SOLVER_KW
-from .common import emit, save_json
+from .common import emit, save_json, use_compile_cache
 
 
 def build_hot(n_demands=5_000, hot_frac=0.002, hot_boost=200.0, seed=0):
@@ -63,4 +63,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

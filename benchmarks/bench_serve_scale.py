@@ -40,7 +40,7 @@ from repro.problems.traffic_engineering import (TrafficProblem,
                                                 k_shortest_paths,
                                                 make_demands, make_topology)
 from repro.service import DispatchConfig, PopService
-from .common import emit, save_json
+from .common import emit, save_json, use_compile_cache
 
 # small per-tenant problems: fleet scale is about tenant COUNT, and tiny
 # instances keep the coalesced launches dominated by dispatch/paging
@@ -250,6 +250,7 @@ def run(fast: bool = False, n_tenants: int = None,
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--tenants", type=int, default=None)

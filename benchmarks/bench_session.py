@@ -33,7 +33,7 @@ from repro.problems.traffic_engineering import (TrafficProblem,
                                                 k_shortest_paths,
                                                 make_demands, make_topology)
 from repro.service import PopService
-from .common import emit, save_json
+from .common import emit, save_json, use_compile_cache
 
 
 def _tenants(fast: bool, rng):
@@ -210,6 +210,7 @@ def _fault_phase(service, insts, tenants) -> dict:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--rounds", type=int, default=None)

@@ -14,7 +14,7 @@ from repro.core import (ExecConfig, SolveConfig, pop, skewed_partition,
                         similarity_report)
 from repro.problems.traffic_engineering import cspf_heuristic
 from .bench_traffic_engineering import build, SOLVER_KW
-from .common import emit, save_json
+from .common import emit, save_json, use_compile_cache
 
 
 def run(n_demands: int = 10_000, ks=(4, 16), seed: int = 0) -> dict:
@@ -53,4 +53,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -14,7 +14,7 @@ from repro.core import ExecConfig, SolveConfig, pop
 from repro.problems.traffic_engineering import (TrafficProblem,
                                                 cspf_heuristic, k_shortest_paths,
                                                 make_demands, make_topology)
-from .common import Timer, emit, save_json
+from .common import Timer, emit, save_json, use_compile_cache
 
 SOLVER_KW = dict(max_iters=10_000, tol_primal=1e-4, tol_gap=1e-4)
 
@@ -75,4 +75,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

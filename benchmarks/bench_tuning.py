@@ -31,7 +31,7 @@ from repro.problems.traffic_engineering import (TrafficProblem,
                                                 make_demands, make_topology)
 from repro.service import PopService
 from repro.tuning import SLOTarget, build_profile, profile_digest
-from .common import emit, save_json
+from .common import emit, save_json, use_compile_cache
 
 SLO = SLOTarget(max_quality_loss=0.02)
 
@@ -151,6 +151,7 @@ def run(fast: bool = False, rounds: int = None, seed: int = 0) -> dict:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--rounds", type=int, default=None)

@@ -51,6 +51,27 @@ def configure_host_devices(n: int | None = None) -> int:
     return n
 
 
+# JAX's persistent compilation cache, where JAX_COMPILATION_CACHE_DIR does
+# not name one: a fixed path in the checkout (git-ignored), since the path
+# is part of what the cache is keyed on
+COMPILE_CACHE_DIR = os.path.normpath(
+    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                 ".jax_cache"))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.  JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself,
+    so where it is set nothing is set here; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
 if __name__ == "__main__":       # scripts/launch.sh queries the preset
     print(find_tcmalloc() or "")
 

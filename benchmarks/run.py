@@ -199,4 +199,6 @@ def _check_against_baseline(snapshot: dict, baseline_path: str,
 
 
 if __name__ == "__main__":
+    from .common import use_compile_cache
+    use_compile_cache()
     main()

@@ -1,10 +1,4 @@
-"""JAX version-compat layer.
-
-The public JAX surface this repo leans on has moved across releases:
-
-* ``shard_map`` lives at ``jax.shard_map`` on new JAX but at
-  ``jax.experimental.shard_map.shard_map`` on 0.4.x;
-* its replication-check kwarg was renamed ``check_rep`` -> ``check_vma``.
+"""The one place the map step reaches ``shard_map``.
 
 Everything that needs ``shard_map`` (the POP map-step backend, gradient
 compression under data parallelism, tests) goes through :func:`shard_map`
@@ -15,40 +9,23 @@ speed.
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable, Optional
 
 import jax
 
-try:  # JAX >= 0.6: top-level export
-    from jax import shard_map as _raw_shard_map
-except ImportError:  # JAX 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-# the replication-safety check kwarg: check_rep (<= 0.5) vs check_vma (>= 0.6)
-_SHARD_MAP_PARAMS = inspect.signature(_raw_shard_map).parameters
-if "check_vma" in _SHARD_MAP_PARAMS:
-    _CHECK_KW: Optional[str] = "check_vma"
-elif "check_rep" in _SHARD_MAP_PARAMS:
-    _CHECK_KW = "check_rep"
-else:
-    _CHECK_KW = None
-
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check: Optional[bool] = None, **kw: Any) -> Callable:
-    """Version-portable ``shard_map``.
+    """``jax.shard_map`` with ``check`` spelled as its ``check_vma``.
 
-    ``check`` maps onto whichever of ``check_vma``/``check_rep`` this JAX
-    understands (dropped silently if neither exists — newest JAX infers
-    it).  POP map steps pass ``check=False``: solver constants (e.g.
+    POP map steps pass ``check=False``: solver constants (e.g.
     power-iteration seed vectors) are intentionally unvarying while the
     problem data varies over the POP axis.
     """
-    if check is not None and _CHECK_KW is not None:
-        kw[_CHECK_KW] = check
-    return _raw_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
+    if check is not None:
+        kw["check_vma"] = check
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def device_count() -> int:

@@ -129,6 +129,7 @@ from __future__ import annotations
 
 import functools
 import inspect
+import math
 from typing import Any, Callable, NamedTuple, Optional, Sequence, Union
 
 import jax
@@ -414,12 +415,13 @@ def dense_ops(lp: LinearProgram) -> OperatorLP:
 
 def dense_K_mv(data, x):
     (K,) = data
-    return K @ x
+    # full f32 products: the TPU's default f32 matmul rounds to bf16
+    return jnp.dot(K, x, precision=jax.lax.Precision.HIGHEST)
 
 
 def dense_KT_mv(data, y):
     (K,) = data
-    return K.T @ y
+    return jnp.dot(y, K, precision=jax.lax.Precision.HIGHEST)
 
 
 def stack_ops(subs: Sequence[OperatorLP]) -> OperatorLP:
@@ -440,17 +442,20 @@ def stack_ops(subs: Sequence[OperatorLP]) -> OperatorLP:
         structs = [dequantize_structured(st) for st in structs]
 
     def padto(a, shape):
-        return jnp.pad(a, [(0, t - s) for s, t in zip(a.shape, shape)])
+        return np.pad(a, [(0, t - s) for s, t in zip(a.shape, shape)])
 
+    # padded and stacked on the host, then placed once: per-lane device
+    # pads would compile one program per distinct lane width
     stacked = {}
     for f in StructuredOperator._fields:
         vals = [getattr(st, f) for st in structs]
         if any(v is None for v in vals):
             stacked[f] = None
             continue
+        vals = [np.asarray(v) for v in vals]
         shape = tuple(max(v.shape[d] for v in vals)
                       for d in range(vals[0].ndim))
-        stacked[f] = jnp.stack([padto(v, shape) for v in vals])
+        stacked[f] = jnp.asarray(np.stack([padto(v, shape) for v in vals]))
     return ops._replace(structured=StructuredOperator(**stacked))
 
 
@@ -533,7 +538,9 @@ class StepEngine(NamedTuple):
     it get their K/KT wrapped functionally instead.  ``prep``, if set,
     normalises the OperatorLP once before solving (the structured engine
     moves ``op.structured`` into ``op.data`` so every downstream consumer
-    sees one payload).
+    sees one payload).  ``kernels`` names what the inner loop runs as on
+    this platform (``"pallas"``, ``"interpret"`` or ``"xla"`` — see
+    ``kernels/ops.kernel_mode``), reported on every result.
     """
 
     name: str
@@ -543,6 +550,7 @@ class StepEngine(NamedTuple):
     backward: Callable
     scale_data: Optional[Callable] = None
     prep: Optional[Callable] = None
+    kernels: str = "xla"
 
 
 def _engine_from_matvecs(name: str, bK: Callable, bKT: Callable,
@@ -623,16 +631,32 @@ def fused_dense_engine(kernel_backend: Optional[str] = None,
         (K_,) = data
         return (K_ * d_r[..., :, None] * d_c[..., None, :],)
 
-    return StepEngine("fused", K, KT, forward, backward, scale_data)
+    return StepEngine("fused", K, KT, forward, backward, scale_data,
+                      kernels=kops.kernel_mode("dense", kernel_backend))
+
+
+@functools.lru_cache(maxsize=1)
+def densified_engine() -> StepEngine:
+    """:func:`fused_dense_engine` for a structured operator: ``prep``
+    materialises its dense K once per solve (``structured_to_dense``)."""
+    def prep(op: OperatorLP) -> OperatorLP:
+        return op._replace(data=(structured_to_dense(op.structured),),
+                           structured=None)
+
+    return fused_dense_engine()._replace(prep=prep)
 
 
 @functools.lru_cache(maxsize=16)
 def fused_structured_engine(
-        kernel_backend: Optional[str] = None) -> StepEngine:
-    """Structured engine over the batched gather/segment-reduce kernels
-    (``kernels/structured_pdhg_step.py`` via ``kernels/ops.py`` dispatch:
-    Pallas on TPU, XLA ``take_along_axis`` reference elsewhere).  One
-    launch per half-step across the whole k-lane stack.  Requires
+        kernel_backend: Optional[str] = None,
+        row_plan: tuple = (), col_plan: tuple = ()) -> StepEngine:
+    """Structured engine over the batched gather/segment-reduce forms
+    (``kernels/ops.py`` dispatch: the XLA ``take_along_axis`` reference on
+    every platform, the Pallas kernels of ``structured_pdhg_step.py`` when
+    forced).  One launch per half-step across the whole k-lane stack.
+    ``row_plan`` / ``col_plan`` are static ragged wide-block plans (see
+    :func:`fused_structured_full_engine`) taken across all lanes; the
+    XLA form applies them, the Pallas lane kernels ignore them.  Requires
     ``op.structured``; ``prep`` moves it into ``op.data`` so the payload
     flows through backends/jit as ordinary traced arrays."""
     from ..kernels import ops as kops
@@ -640,17 +664,19 @@ def fused_structured_engine(
     kw: dict = dict(backend=kernel_backend)
 
     def K(data, x):
-        return kops.smatvec(data, x)
+        return kops.smatvec(data, x, plan=row_plan)
 
     def KT(data, y):
-        return kops.smatvec_t(data, y)
+        return kops.smatvec_t(data, y, plan=col_plan)
 
     def forward(data, x, c, l, u, tau, kty):
-        return kops.structured_forward_step(data, x, c, l, u, tau, kty, **kw)
+        return kops.structured_forward_step(data, x, c, l, u, tau, kty,
+                                            plan=row_plan, **kw)
 
     def backward(data, y, q, sigma, ineq_mask, kx_new, kx_prev):
         return kops.structured_backward_step(data, y, q, sigma, ineq_mask,
-                                             kx_new, kx_prev, **kw)
+                                             kx_new, kx_prev, plan=col_plan,
+                                             **kw)
 
     def prep(op: OperatorLP) -> OperatorLP:
         # the lane kernels have no dequant path — quantized payloads
@@ -659,7 +685,8 @@ def fused_structured_engine(
                            structured=None)
 
     return StepEngine("fused_structured", K, KT, forward, backward,
-                      scale_structured, prep)
+                      scale_structured, prep,
+                      kernels=kops.kernel_mode("gather", kernel_backend))
 
 
 @functools.lru_cache(maxsize=16)
@@ -706,12 +733,22 @@ def fused_structured_full_engine(
         return op._replace(data=op.structured, structured=None)
 
     return StepEngine("fused_structured_full", K, KT, forward, backward,
-                      scale_structured, prep)
+                      scale_structured, prep,
+                      kernels=kops.kernel_mode("gather", kernel_backend))
 
 
+# on a TPU, auto densifies a structured stack up to this many bytes of f32
+# K: an eighth of a 16 GB v5e, leaving room for the solver's copies
+DENSE_MAX_BYTES = 2 << 30
 # auto picks fused_structured_full only above this many stored wide-bucket
 # elements: below it the one-hot fold is cheap and the lane kernels win
 FULL_ENGINE_MIN_WIDE_ELEMS = 65_536
+# a k-lane stack gets a ragged wide-block plan only where one side's wide
+# bucket stores at least this many elements across all lanes: there the
+# uniform-width padding dominates a half-step (a k=64 lane stack of the
+# paper-scale traffic instance stores 7.5x its nonzeros), while below it
+# one full-width block keeps the engine independent of the data
+LANE_PLAN_MIN_WIDE_ELEMS = 1 << 22
 # column chunk the ragged wide-block plan is quantised to
 WIDE_BLOCK_COLS = 128
 
@@ -725,28 +762,34 @@ def _wide_elems(s: StructuredOperator) -> int:
             + s.wcol_idx.shape[-2] * s.wcol_idx.shape[-1])
 
 
+@jax.jit
+def _effective_widths(wval):
+    """[D] effective width of each wide-bucket column, the most over all
+    lanes: the index of its last nonzero + 1 (0 if empty)."""
+    ww, d = wval.shape[-2:]
+    depth = jnp.arange(1, ww + 1, dtype=jnp.int32)[:, None]
+    last = jnp.max(jnp.where(wval != 0, depth, 0), axis=-2)
+    return jnp.max(last.reshape(-1, d), axis=0)
+
+
 def _wide_block_plan(wval) -> tuple:
     """Static ragged plan ``((c0, c1, wb), ...)`` over a wide bucket's
     descending-width columns: chunks of :data:`WIDE_BLOCK_COLS` columns,
-    each sliced to its own max effective width (from ``val != 0`` —
-    exact, since zero coefficients contribute nothing) rounded up to the
-    f32 sublane multiple.  Needs a concrete array; on tracers (a user
-    jitting ``solve_stacked`` around resolution) falls back to one
-    full-width block — correct, just unsliced."""
+    each sliced to its own max effective width over all lanes (from
+    ``val != 0`` — exact, since zero coefficients contribute nothing)
+    rounded up to the f32 sublane multiple.  Needs a concrete array; on
+    tracers (a user jitting ``solve_stacked`` around resolution) falls
+    back to one full-width block — correct, just unsliced."""
     if isinstance(wval, jax.core.Tracer):
         ww = wval.shape[-2]
         return ((0, wval.shape[-1], ww),)
+    ww, d = wval.shape[-2:]
     # deliberately host-side: the plan must be static (baked into the
     # lru-cached engine), and the Tracer guard above already routed any
-    # traced value away — what reaches here is concrete by construction
-    v = np.asarray(wval)  # popcheck: disable=host-sync-in-hot-path
-    if v.ndim == 3:
-        v = v[0]
-    ww, d = v.shape
-    nz = v != 0.0
-    # per-column effective width: index of last nonzero + 1 (0 if empty)
-    counts = np.where(nz.any(axis=0),
-                      ww - np.argmax(nz[::-1, :], axis=0), 0)
+    # traced value away — what reaches here is concrete by construction;
+    # only the [D] widths leave the device
+    counts = np.asarray(  # popcheck: disable=host-sync-in-hot-path
+        _effective_widths(wval))
     plan = []
     for c0 in range(0, d, WIDE_BLOCK_COLS):
         c1 = min(c0 + WIDE_BLOCK_COLS, d)
@@ -755,6 +798,19 @@ def _wide_block_plan(wval) -> tuple:
         wb = min(max(8, -(-wmax // 8) * 8), ww)
         plan.append((c0, c1, wb))
     return tuple(plan) if plan else ((0, d, ww),)
+
+
+def _lane_plan(wval) -> tuple:
+    """The k-lane engine's plan for one side: ragged only for wide buckets
+    of at least :data:`LANE_PLAN_MIN_WIDE_ELEMS` stored elements."""
+    if math.prod(wval.shape) < LANE_PLAN_MIN_WIDE_ELEMS:
+        return ()
+    return _wide_block_plan(wval)
+
+
+def _dense_bytes(op: OperatorLP) -> int:
+    """Bytes of the f32 dense K of a (stacked) operator."""
+    return 4 * math.prod(op.c.shape) * op.q.shape[-1]
 
 
 def is_dense_ops(op: OperatorLP) -> bool:
@@ -786,12 +842,21 @@ def select_engine(op: OperatorLP, K_mv: Callable = dense_K_mv,
     ``solve_full`` baseline at paper scale, where the lane path's
     uniform-width padding and one-hot fold dominate (measured 13x on the
     traffic matvec pair at 3000 demands); everything else takes
-    ``matvec``."""
+    ``matvec``.
+
+    On a TPU, a structured stack whose dense form fits
+    :data:`DENSE_MAX_BYTES` is densified and takes ``fused`` too: there
+    the ELL gathers run as XLA gathers, ~100M elements/s on a v5e (a k=1
+    3,000-demand traffic solve at 9.2 ms per iteration, a k=64
+    500,000-demand stack at 3.2 s), while the dense Pallas kernels
+    stream K at HBM bandwidth."""
     pref = getattr(K_mv, "preferred_engine", None)
     if pref is not None:
         return pref
     dense = (K_mv is dense_K_mv and KT_mv is dense_KT_mv and is_dense_ops(op))
-    if dense and jax.default_backend() == "tpu":
+    tpu = jax.default_backend() == "tpu"
+    if tpu and (dense or (op.structured is not None
+                          and _dense_bytes(op) <= DENSE_MAX_BYTES)):
         return "fused"
     if op.structured is not None:
         s = op.structured
@@ -813,6 +878,21 @@ def engine_name(engine: Union[str, "StepEngine"]) -> str:
     return engine if isinstance(engine, str) else engine.name
 
 
+# kernel family behind each named engine (``kernels/ops.kernel_mode``)
+_ENGINE_FAMILY = {"matvec": "matvec", "fused": "dense",
+                  "fused_structured": "gather",
+                  "fused_structured_full": "gather"}
+
+
+def engine_kernels(engine: Union[str, "StepEngine"]) -> str:
+    """What a resolved engine's inner loop runs as here: ``"pallas"``,
+    ``"interpret"`` or ``"xla"`` (a named engine with default kernels)."""
+    if isinstance(engine, StepEngine):
+        return engine.kernels
+    from ..kernels import ops as kops
+    return kops.kernel_mode(_ENGINE_FAMILY[engine])
+
+
 def resolve_engine(engine: Union[None, str, StepEngine], op: OperatorLP,
                    K_mv: Callable = dense_K_mv,
                    KT_mv: Callable = dense_KT_mv) -> StepEngine:
@@ -828,12 +908,14 @@ def resolve_engine(engine: Union[None, str, StepEngine], op: OperatorLP,
     if engine == "matvec":
         return matvec_engine(K_mv, KT_mv)
     if engine == "fused":
-        if not is_dense_ops(op):
-            raise ValueError(
-                "engine='fused' needs dense operator data (op.data == (K,) "
-                "with K [..., M, N]); structured operators use "
-                "engine='matvec' or 'fused_structured'")
-        return fused_dense_engine()
+        if is_dense_ops(op):
+            return fused_dense_engine()
+        if op.structured is not None:
+            return densified_engine()
+        raise ValueError(
+            "engine='fused' needs dense operator data (op.data == (K,) "
+            "with K [..., M, N]) or op.structured to densify; other "
+            "operators use engine='matvec'")
     if engine == "fused_structured":
         if op.structured is None:
             raise ValueError(
@@ -841,7 +923,9 @@ def resolve_engine(engine: Union[None, str, StepEngine], op: OperatorLP,
                 "(StructuredOperator index metadata attached by the "
                 "problem's build_sub); operators without it use "
                 "engine='matvec'")
-        return fused_structured_engine()
+        s = op.structured
+        return fused_structured_engine(
+            row_plan=_lane_plan(s.wrow_val), col_plan=_lane_plan(s.wcol_val))
     if engine == "fused_structured_full":
         s = op.structured
         if s is None or s.row_fold is None:

@@ -159,6 +159,7 @@ class POPResult:
     # plan=)
     backend: Optional[str] = None
     engine: Optional[str] = None
+    kernels: Optional[str] = None     # "pallas" | "interpret" | "xla"
     plan_source: Optional[str] = None
     # [k] per-lane divergence-quarantine flags from the solver (lanes whose
     # KKT score went non-finite or blew up; see pdhg.solve_stacked) — what
@@ -530,6 +531,7 @@ def finish_prepared(prep: PreparedSolve, res: SolveResult,
         x=np.asarray(res.x), y=np.asarray(res.y),
         plan=p, warm_stats=prep.warm_stats,
         backend=prep.backend, engine=pdhg.engine_name(prep.engine),
+        kernels=pdhg.engine_kernels(prep.engine),
         plan_source=prep.plan_source,
         diverged=(None if res.diverged is None
                   else np.asarray(res.diverged)),
@@ -641,6 +643,7 @@ class FullResult:
     build_time_s: float
     backend: Optional[str] = None
     engine: Optional[str] = None
+    kernels: Optional[str] = None
 
 
 def prepare_full(problem: POPProblem, *,
@@ -682,7 +685,8 @@ def finish_full(prep: PreparedSolve, res: SolveResult,
     alloc = np.asarray(prep.problem.extract(op, np.asarray(res1.x), idx))
     return FullResult(alloc=alloc, res=res1, solve_time_s=solve_time_s,
                       build_time_s=prep.build_time_s, backend=prep.backend,
-                      engine=pdhg.engine_name(prep.engine))
+                      engine=pdhg.engine_name(prep.engine),
+                      kernels=pdhg.engine_kernels(prep.engine))
 
 
 def solve_full_ex(problem: POPProblem, *,

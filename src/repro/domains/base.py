@@ -52,6 +52,7 @@ class StepOutcome:
     warm_state: Any
     backend: Optional[str] = None
     engine: Optional[str] = None
+    kernels: Optional[str] = None
     plan_cache: str = "miss"
     warm_fraction: Optional[float] = None
     solve_time_s: float = 0.0

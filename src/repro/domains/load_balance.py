@@ -62,6 +62,7 @@ def _step(inst: BalanceInstance, solve_cfg: SolveConfig,
         warm_state=res,
         backend=res.extra.get("backend"),
         engine=res.extra.get("engine"),
+        kernels=res.extra.get("kernels"),
         plan_cache=res.extra.get("plan_cache", "miss"),
         warm_fraction=res.extra.get("warm_fraction"),
         solve_time_s=res.solve_time_s,

@@ -20,6 +20,8 @@ SPEC = register(DomainSpec(
     instance_types=(TrafficProblem,),
     describe="max-total-flow WAN TE (commodities onto k-shortest paths)",
     problem=lambda inst: inst,
+    # served allocations honour every capacity exactly
+    round=lambda inst, f: inst.fit_to_capacity(f),
     # the SLO tuner's quality scalar (repro.tuning)
     quality=lambda m: m["total_flow"],
     default_solve=SolveConfig(k=8, strategy="stratified"),

@@ -18,8 +18,9 @@ consumes for free:
 backward step; the dual extrapolation uses 2*K x_new - K x_prev — linearity
 of K — instead of a second matvec on the extrapolated point.)
 
-Scalars (tau, sigma) ride in SMEM-like (1, 1) blocks so the kernel stays
-shape-polymorphic over the POP batch.
+Vectors travel as ``[k, 1, n]`` views with the lane axis squeezed out of
+their blocks (see ``pdhg_matvec.py``), and the per-lane step sizes (tau,
+sigma) as one ``[k]`` array in SMEM read at the lane's grid index.
 """
 
 from __future__ import annotations
@@ -31,7 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pdhg_matvec import BLOCK_M, BLOCK_N
+from .pdhg_matvec import (BLOCK_M, BLOCK_N, lane_vec, row_times_tile,
+                          row_times_tile_t)
 
 
 def _fused_forward_kernel(a_ref, x_ref, c_ref, l_ref, u_ref, kty_ref,
@@ -46,19 +48,15 @@ def _fused_forward_kernel(a_ref, x_ref, c_ref, l_ref, u_ref, kty_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    tau = tau_ref[0, 0]
-    x_new = jnp.clip(x_ref[0] - tau * (c_ref[0] + kty_ref[0]),
-                     l_ref[0], u_ref[0])
-    xn_ref[0, :] = x_new.astype(xn_ref.dtype)
-    a = a_ref[0]                        # [bm, bn]
-    acc_ref[...] += jax.lax.dot_general(
-        a, x_new[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
+    tau = tau_ref[pl.program_id(0)]
+    x_new = jnp.clip(x_ref[...] - tau * (c_ref[...] + kty_ref[...]),
+                     l_ref[...], u_ref[...])
+    xn_ref[...] = x_new.astype(xn_ref.dtype)
+    acc_ref[...] += row_times_tile(x_new, a_ref[...].astype(jnp.float32))
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finish():
-        kx_ref[0, :] = acc_ref[...].astype(kx_ref.dtype)
+        kx_ref[...] = acc_ref[...].astype(kx_ref.dtype)
 
 
 def _fused_backward_kernel(a_ref, y_ref, q_ref, mask_ref, kxn_ref, kxp_ref,
@@ -68,19 +66,19 @@ def _fused_backward_kernel(a_ref, y_ref, q_ref, mask_ref, kxn_ref, kxp_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    sigma = sig_ref[0, 0]
-    y_new = y_ref[0] + sigma * (2.0 * kxn_ref[0] - kxp_ref[0] - q_ref[0])
-    y_new = jnp.where(mask_ref[0], jnp.maximum(y_new, 0.0), y_new)
-    yn_ref[0, :] = y_new.astype(yn_ref.dtype)
-    a = a_ref[0]                        # [bm, bn]
-    acc_ref[...] += jax.lax.dot_general(
-        a, y_new[:, None], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
+    sigma = sig_ref[pl.program_id(0)]
+    y_new = y_ref[...] + sigma * (2.0 * kxn_ref[...] - kxp_ref[...]
+                                  - q_ref[...])
+    y_new = jnp.where(mask_ref[...] != 0, jnp.maximum(y_new, 0.0), y_new)
+    yn_ref[...] = y_new.astype(yn_ref.dtype)
+    acc_ref[...] += row_times_tile_t(y_new, a_ref[...].astype(jnp.float32))
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finish():
-        kty_ref[0, :] = acc_ref[...].astype(kty_ref.dtype)
+        kty_ref[...] = acc_ref[...].astype(kty_ref.dtype)
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "interpret"))
@@ -93,23 +91,23 @@ def fused_forward_step(A, x, c, l, u, tau, kty, *,
     k, M, N = A.shape
     assert M % block_m == 0 and N % block_n == 0
     grid = (k, M // block_m, N // block_n)
-    vec_n = pl.BlockSpec((1, block_n), lambda b, i, j: (b, j))
-    vec_m = pl.BlockSpec((1, block_m), lambda b, i, j: (b, i))
-    out = [jax.ShapeDtypeStruct((k, N), jnp.float32),
-           jax.ShapeDtypeStruct((k, M), jnp.float32)]
-    return pl.pallas_call(
+    vec_n = lane_vec(block_n, 2)
+    out = [jax.ShapeDtypeStruct((k, 1, N), jnp.float32),
+           jax.ShapeDtypeStruct((k, 1, M), jnp.float32)]
+    row = lambda v: v[:, None, :]
+    xn, kx = pl.pallas_call(
         _fused_forward_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_m, block_n), lambda b, i, j: (b, i, j)),
-            vec_n, vec_n, vec_n, vec_n, vec_n,
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
+            pl.BlockSpec((None, block_m, block_n), lambda b, i, j: (b, i, j)),
+            vec_n, vec_n, vec_n, vec_n, vec_n, _SMEM,
         ],
-        out_specs=[vec_n, vec_m],
+        out_specs=[vec_n, lane_vec(block_m, 1)],
         out_shape=out,
-        scratch_shapes=[pltpu.VMEM((block_m,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_m), jnp.float32)],
         interpret=interpret,
-    )(A, x, c, l, u, kty, tau[:, None])
+    )(A, row(x), row(c), row(l), row(u), row(kty), tau.astype(jnp.float32))
+    return xn[:, 0], kx[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "interpret"))
@@ -121,20 +119,21 @@ def fused_backward_step(A, y, q, sigma, ineq_mask, kx_new, kx_prev, *,
     k, M, N = A.shape
     assert M % block_m == 0 and N % block_n == 0
     grid = (k, N // block_n, M // block_m)
-    vec_m = pl.BlockSpec((1, block_m), lambda b, j, i: (b, i))
-    vec_n = pl.BlockSpec((1, block_n), lambda b, j, i: (b, j))
-    out = [jax.ShapeDtypeStruct((k, M), jnp.float32),
-           jax.ShapeDtypeStruct((k, N), jnp.float32)]
-    return pl.pallas_call(
+    vec_m = lane_vec(block_m, 2)
+    out = [jax.ShapeDtypeStruct((k, 1, M), jnp.float32),
+           jax.ShapeDtypeStruct((k, 1, N), jnp.float32)]
+    row = lambda v: v[:, None, :]
+    yn, kty = pl.pallas_call(
         _fused_backward_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_m, block_n), lambda b, j, i: (b, i, j)),
-            vec_m, vec_m, vec_m, vec_m, vec_m,
-            pl.BlockSpec((1, 1), lambda b, j, i: (b, 0)),
+            pl.BlockSpec((None, block_m, block_n), lambda b, j, i: (b, i, j)),
+            vec_m, vec_m, vec_m, vec_m, vec_m, _SMEM,
         ],
-        out_specs=[vec_m, vec_n],
+        out_specs=[vec_m, lane_vec(block_n, 1)],
         out_shape=out,
-        scratch_shapes=[pltpu.VMEM((block_n,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_n), jnp.float32)],
         interpret=interpret,
-    )(A, y, q, ineq_mask, kx_new, kx_prev, sigma[:, None])
+    )(A, row(y), row(q), row(ineq_mask.astype(jnp.int32)), row(kx_new),
+      row(kx_prev), sigma.astype(jnp.float32))
+    return yn[:, 0], kty[:, 0]

@@ -5,12 +5,18 @@ results back, and (b) platform dispatch.  Every wrapper takes a
 ``backend`` keyword:
 
 ``None`` / ``"auto"``
-    The fast path for the platform: compiled Pallas on TPU, the pure-jnp
-    reference (``ref.py`` — algebraically identical, XLA-fused) everywhere
-    else.  Interpret-mode Pallas is ~1000x too slow for a PDHG inner loop,
-    so it is never chosen implicitly.
+    The fast path for the platform.  Dense kernels: compiled Pallas on
+    TPU, the pure-jnp reference (``ref.py`` — algebraically identical,
+    XLA-fused) everywhere else.  Gather kernels (the structured and
+    streaming-full families): the ``ref.py`` form on EVERY platform — the
+    TPU's Pallas compiler (Mosaic) lowers a gather only inside one
+    128-lane vreg, and these kernels gather from whole lane vectors
+    thousands of columns long (see ``README.md``, "What runs on a TPU").
+    Interpret-mode Pallas is ~1000x too slow for a PDHG inner loop, so it
+    is never chosen implicitly, and no mode is ever retried in another.
 ``"pallas"``
-    Force compiled Pallas (fails off-TPU — debugging aid).
+    Force compiled Pallas (fails off-TPU, and for the gather families on
+    the TPU too — debugging aid).
 ``"interpret"``
     Force the Pallas interpreter (runs anywhere; exercises the real kernel
     bodies + padding logic on CPU — what ``tests/test_kernels.py`` and the
@@ -60,14 +66,30 @@ _MODES = (None, "auto", "pallas", "interpret", "xla")
 STRUCT_ALIGN = 128
 
 
-def _resolve_mode(backend: str | None) -> str:
-    """'pallas' | 'interpret' | 'xla' from a user-facing backend name."""
+def _resolve_mode(backend: str | None, gather: bool = False) -> str:
+    """'pallas' | 'interpret' | 'xla' from a user-facing backend name;
+    ``gather`` marks the ELL gather families, whose fast path is the XLA
+    form on every platform."""
     if backend not in _MODES:
         raise ValueError(f"unknown kernel backend {backend!r}; "
                          f"expected one of {_MODES}")
     if backend in (None, "auto"):
+        if gather:
+            return "xla"
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return backend
+
+
+def kernel_mode(family: str, backend: str | None = None) -> str:
+    """What a step engine's kernels run as here — ``"pallas"``,
+    ``"interpret"`` or ``"xla"`` — for a kernel ``family`` (``"dense"``,
+    ``"gather"``, or ``"matvec"`` for the vmapped jnp engine, always
+    ``"xla"``).  Engines record it so every result names what ran."""
+    if family == "matvec":
+        return "xla"
+    if family not in ("dense", "gather"):
+        raise ValueError(f"unknown kernel family {family!r}")
+    return _resolve_mode(backend, gather=family == "gather")
 
 
 def _pad_to(a: jnp.ndarray, axis: int, mult: int, value: float = 0.0):
@@ -151,17 +173,16 @@ def fused_backward_step(A, y, q, sigma, ineq_mask, kx_new, kx_prev, *,
 # ``core/pdhg.StructuredOperator`` with batched [k, W, {M|N}] leaves
 # --------------------------------------------------------------------------
 
-def smatvec(s, x):
+def smatvec(s, x, *, plan=()):
     """kx = K x through the row-side gather layout.  Pure gather-reduce —
-    the XLA form is the fast path on every platform for the out-of-loop
-    uses (power iteration, equilibration probes, final KKT report); only
-    the inner-loop half-steps get dedicated Pallas kernels."""
-    return _ref.smatvec(s, x)
+    the XLA form is the fast path on every platform; ``plan`` is a static
+    ragged wide-block plan (``core/pdhg._wide_block_plan``)."""
+    return _ref.smatvec(s, x, plan)
 
 
-def smatvec_t(s, y):
+def smatvec_t(s, y, *, plan=()):
     """kty = K^T y through the column-side gather layout."""
-    return _ref.smatvec_t(s, y)
+    return _ref.smatvec_t(s, y, plan)
 
 
 def _pad_row_side(s):
@@ -185,14 +206,15 @@ def _pad_col_side(s):
         wcol_ids=_pad_to(s.wcol_ids, 1, STRUCT_ALIGN))
 
 
-def structured_forward_step(s, x, c, l, u, tau, kty, *,
+def structured_forward_step(s, x, c, l, u, tau, kty, *, plan=(),
                             backend: str | None = None):
     """(x_new, kx) for a structured operator: one gather/segment-reduce
-    launch for the whole k-stack (Pallas on TPU/interpret, XLA reference
-    elsewhere)."""
-    mode = _resolve_mode(backend)
+    launch for the whole k-stack (the XLA reference unless the Pallas
+    kernel is forced)."""
+    mode = _resolve_mode(backend, gather=True)
     if mode == "xla":
-        return _ref.structured_forward_step(s, x, c, l, u, tau[:, None], kty)
+        return _ref.structured_forward_step(s, x, c, l, u, tau[:, None], kty,
+                                            plan)
     M = s.row_idx.shape[-1]
     N = x.shape[1]
     pad_vec = lambda v: _pad_to(v, 1, STRUCT_ALIGN)
@@ -203,12 +225,12 @@ def structured_forward_step(s, x, c, l, u, tau, kty, *,
 
 
 def structured_backward_step(s, y, q, sigma, ineq_mask, kx_new, kx_prev, *,
-                             backend: str | None = None):
+                             plan=(), backend: str | None = None):
     """(y_new, kty) for a structured operator."""
-    mode = _resolve_mode(backend)
+    mode = _resolve_mode(backend, gather=True)
     if mode == "xla":
         return _ref.structured_backward_step(s, y, q, sigma[:, None],
-                                             ineq_mask, kx_new, kx_prev)
+                                             ineq_mask, kx_new, kx_prev, plan)
     N = s.col_idx.shape[-1]
     M = y.shape[1]
     pad_vec = lambda v: _pad_to(v, 1, STRUCT_ALIGN)
@@ -279,9 +301,9 @@ def structured_full_forward_step(s, x, c, l, u, tau, kty, *, plan=(),
                                  block_w: int = _structured.FULL_BLOCK_W,
                                  block_d: int = _structured.FULL_BLOCK_D):
     """(x_new, kx) for the single-lane full problem: one M-blocked
-    streaming launch (Pallas on TPU/interpret, ragged-plan XLA reference
-    elsewhere)."""
-    mode = _resolve_mode(backend)
+    streaming launch (the ragged-plan XLA reference unless the Pallas
+    kernel is forced)."""
+    mode = _resolve_mode(backend, gather=True)
     if mode == "xla" or s.row_idx.shape[0] != 1:
         return _ref.structured_full_forward_step(s, x, c, l, u,
                                                  tau[:, None], kty, plan)
@@ -306,7 +328,7 @@ def structured_full_backward_step(s, y, q, sigma, ineq_mask, kx_new,
                                   block_w: int = _structured.FULL_BLOCK_W,
                                   block_d: int = _structured.FULL_BLOCK_D):
     """(y_new, kty) for the single-lane full problem (column side)."""
-    mode = _resolve_mode(backend)
+    mode = _resolve_mode(backend, gather=True)
     if mode == "xla" or s.col_idx.shape[0] != 1:
         return _ref.structured_full_backward_step(
             s, y, q, sigma[:, None], ineq_mask, kx_new, kx_prev, plan)

@@ -10,19 +10,20 @@ scatters anywhere.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
 def bmatvec(A: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """y[k, m] = sum_n A[k, m, n] * x[k, n]   (f32 accumulation)."""
-    return jnp.einsum("kmn,kn->km", A, x,
-                      preferred_element_type=jnp.float32)
+    return jnp.einsum("kmn,kn->km", A, x, preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def bmatvec_t(A: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     """x[k, n] = sum_m A[k, m, n] * y[k, m]   (A read transposed)."""
-    return jnp.einsum("kmn,km->kn", A, y,
-                      preferred_element_type=jnp.float32)
+    return jnp.einsum("kmn,km->kn", A, y, preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _bgather(v: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -33,31 +34,39 @@ def _bgather(v: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
 
 def _gather_side(idx, val, widx, wval, wids, v, n_out):
     """One direction of the two-bucket ELL matvec (``K x`` through the row
-    side, ``K^T y`` through the column side):
+    side, ``K^T y`` through the column side) for an operator without fold
+    maps:
 
         out = sum_w val[:, w, :] * v[idx[:, w, :]]              (narrow)
         out += scatter(wids, sum_w wval[:, w, :] * v[widx[:, w, :]])
 
     All gathers; the wide-bucket results land via a one-hot accumulation
-    (bucket ids are distinct, so order never matters).  Padding entries
-    (idx 0, val 0) and empty buckets contribute exact zeros.
+    (bucket ids are distinct, so order never matters), contracted at full
+    f32 precision.  Padding entries (idx 0, val 0) and empty buckets
+    contribute exact zeros.
     """
     out = jnp.sum(val * _bgather(v, idx), axis=-2)           # [k, n_out]
     wide = jnp.sum(wval * _bgather(v, widx), axis=-2)        # [k, D]
     onehot = (wids[:, :, None] == jnp.arange(n_out)[None, None, :])
-    return out + jnp.einsum("kd,kdm->km", wide,
-                            onehot.astype(wide.dtype))
+    return out + jnp.einsum("kd,kdm->km", wide, onehot.astype(wide.dtype),
+                            precision=jax.lax.Precision.HIGHEST)
 
 
-def smatvec(s, x):
+def smatvec(s, x, plan=()):
     """kx[k, m] = (K x) through the row-side gather layout of a
-    ``core/pdhg.StructuredOperator`` (padding entries carry val 0)."""
+    ``core/pdhg.StructuredOperator`` (padding entries carry val 0).  An
+    operator with fold maps takes the fold-map add-back and the ragged
+    ``plan`` of :func:`smatvec_full`."""
+    if s.row_fold is not None:
+        return smatvec_full(s, x, plan)
     return _gather_side(s.row_idx, s.row_val, s.wrow_idx, s.wrow_val,
                         s.wrow_ids, x, s.row_idx.shape[-1])
 
 
-def smatvec_t(s, y):
+def smatvec_t(s, y, plan=()):
     """kty[k, n] = (K^T y) through the column-side gather layout."""
+    if s.col_fold is not None:
+        return smatvec_t_full(s, y, plan)
     return _gather_side(s.col_idx, s.col_val, s.wcol_idx, s.wcol_val,
                         s.wcol_ids, y, s.col_idx.shape[-1])
 
@@ -89,17 +98,18 @@ def fused_backward_step(A, y, q, sigma, ineq_mask, kx_new, kx_prev):
     return y_new, bmatvec_t(A, y_new)
 
 
-def structured_forward_step(s, x, c, l, u, tau, kty):
+def structured_forward_step(s, x, c, l, u, tau, kty, plan=()):
     """Structured-operator forward half-step (ELL gather-reduce matvec)."""
     x_new = jnp.clip(x - tau * (c + kty), l, u)
-    return x_new, smatvec(s, x_new)
+    return x_new, smatvec(s, x_new, plan)
 
 
-def structured_backward_step(s, y, q, sigma, ineq_mask, kx_new, kx_prev):
+def structured_backward_step(s, y, q, sigma, ineq_mask, kx_new, kx_prev,
+                             plan=()):
     """Structured-operator backward half-step."""
     y_new = y + sigma * (2.0 * kx_new - kx_prev - q)
     y_new = jnp.where(ineq_mask, jnp.maximum(y_new, 0.0), y_new)
-    return y_new, smatvec_t(s, y_new)
+    return y_new, smatvec_t(s, y_new, plan)
 
 
 # --------------------------------------------------------------------------

@@ -1,4 +1,11 @@
-"""Pallas TPU kernels: FUSED PDHG half-steps for STRUCTURED operators.
+"""Pallas kernels: FUSED PDHG half-steps for STRUCTURED operators.
+
+Not compiled on a TPU: Mosaic, the TPU's Pallas compiler, lowers a gather
+only inside one 128-lane vreg, and every kernel here gathers from whole
+lane vectors thousands of columns long.  ``kernels/ops.py`` therefore
+dispatches these half-steps to their XLA forms (``ref.py``) on every
+platform; the kernels run in interpret mode, where the tests check them
+against those forms (see ``README.md``, "What runs on a TPU").
 
 The structured LPs (Gavel per-job rows, traffic per-commodity path sums,
 load-balancing server groups) apply K through segment-sums and gathers, not
@@ -25,8 +32,11 @@ in VMEM (POP sub-problems are small by construction — the k^2 variable
 reduction is the paper's point — so a lane's [N] + [W, M] blocks fit
 comfortably).  The nnz axis rides the sublanes (arrays are [W, M]
 nnz-major) so the reduce is a sublane reduction and rows/cols stay on the
-128-wide lane axis.  Scalars (tau, sigma) ride in (1, 1) blocks so the
-kernel stays shape-polymorphic over the POP batch.
+128-wide lane axis.  The lane axis is squeezed out of every block, so the
+last two block dims are an array's own (the TPU's block-shape rule), and
+the per-lane step sizes (tau, sigma) are one ``[k]`` array in SMEM.  A
+lane of a k=64 paper-scale traffic stack outgrows the default scoped
+VMEM, so the launch sets its VMEM limit from the lane's size.
 
 The FULL unpartitioned problem at paper scale does NOT fit a lane in
 VMEM; it takes the **M-blocked streaming family** below
@@ -61,6 +71,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gather_side(idx, val, widx, wval, wids, v, n_out):
@@ -79,11 +90,11 @@ def _forward_kernel(ri_ref, rv_ref, wri_ref, wrv_ref, wrids_ref,
                     x_ref, c_ref, l_ref, u_ref, kty_ref, tau_ref,
                     xn_ref, kx_ref):
     """grid = (k,): one lane per program, everything VMEM-resident."""
-    tau = tau_ref[0, 0]
+    tau = tau_ref[pl.program_id(0)]
     x_new = jnp.clip(x_ref[0] - tau * (c_ref[0] + kty_ref[0]),
                      l_ref[0], u_ref[0])
     xn_ref[0, :] = x_new.astype(xn_ref.dtype)
-    kx = _gather_side(ri_ref[0], rv_ref[0], wri_ref[0], wrv_ref[0],
+    kx = _gather_side(ri_ref[...], rv_ref[...], wri_ref[...], wrv_ref[...],
                       wrids_ref[0], x_new, kx_ref.shape[-1])
     kx_ref[0, :] = kx.astype(kx_ref.dtype)
 
@@ -92,18 +103,60 @@ def _backward_kernel(ci_ref, cv_ref, wci_ref, wcv_ref, wcids_ref,
                      y_ref, q_ref, mask_ref, kxn_ref, kxp_ref, sig_ref,
                      yn_ref, kty_ref):
     """grid = (k,): dual tail + adjoint gather-reduce."""
-    sigma = sig_ref[0, 0]
+    sigma = sig_ref[pl.program_id(0)]
     y_new = y_ref[0] + sigma * (2.0 * kxn_ref[0] - kxp_ref[0] - q_ref[0])
-    y_new = jnp.where(mask_ref[0], jnp.maximum(y_new, 0.0), y_new)
+    y_new = jnp.where(mask_ref[0] != 0, jnp.maximum(y_new, 0.0), y_new)
     yn_ref[0, :] = y_new.astype(yn_ref.dtype)
-    kty = _gather_side(ci_ref[0], cv_ref[0], wci_ref[0], wcv_ref[0],
+    kty = _gather_side(ci_ref[...], cv_ref[...], wci_ref[...], wcv_ref[...],
                        wcids_ref[0], y_new, kty_ref.shape[-1])
     kty_ref[0, :] = kty.astype(kty_ref.dtype)
 
 
-def _vec(b):
-    """BlockSpec for a per-lane [1, ...] full block."""
-    return pl.BlockSpec(b, lambda i: (i,) + (0,) * (len(b) - 1))
+def _lane(shape):
+    """BlockSpec for one lane of a ``[k, ...]`` array: the lane axis is
+    squeezed, so the last two block dims are the array's own (the TPU's
+    block-shape rule refuses a ``(1, n)`` block over ``[k, n]``)."""
+    return pl.BlockSpec((None,) + tuple(shape),
+                        lambda i: (i,) + (0,) * len(shape))
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _lane_vmem_limit(arrays) -> int:
+    """Scoped-VMEM budget for a whole-lane launch: every operand and output
+    holds one lane, double-buffered, plus headroom for the gathered
+    temporaries.  A lane of a k=64 paper-scale traffic stack (~31k
+    columns) outgrows the 16 MiB default, so the limit follows the lane
+    size instead, capped below the chip's 128 MiB."""
+    per_lane = sum(a.size // a.shape[0] * a.dtype.itemsize for a in arrays)
+    return min(max(6 * per_lane, 16 << 20), 100 << 20)
+
+
+def _lane_call(kernel, s_side, vectors, step, n_out, interpret):
+    """Launch a whole-lane half-step: ``s_side`` = the five ELL leaves of
+    one side ([k, W, S] / [k, Ww, D] / [k, D]), ``vectors`` the [k, n]
+    tail operands, ``step`` the [k] step sizes; returns the updated
+    iterate [k, n] and the matvec product [k, n_out]."""
+    idx, val, widx, wval, wids = s_side
+    k, n = vectors[0].shape
+    row = lambda v: v[:, None, :]
+    ins = [idx, val, widx, wval, row(wids)] + [
+        row(v.astype(jnp.int32) if v.dtype == jnp.bool_ else v)
+        for v in vectors]
+    out = [jax.ShapeDtypeStruct((k, 1, n), jnp.float32),
+           jax.ShapeDtypeStruct((k, 1, n_out), jnp.float32)]
+    new, prod = pl.pallas_call(
+        kernel,
+        grid=(k,),
+        in_specs=[_lane(a.shape[1:]) for a in ins] + [_SMEM],
+        out_specs=[_lane((1, n)), _lane((1, n_out))],
+        out_shape=out,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_lane_vmem_limit(ins + out)),
+        interpret=interpret,
+    )(*ins, step.astype(jnp.float32))
+    return new[:, 0], prod[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -113,28 +166,10 @@ def structured_forward_step(s, x, c, l, u, tau, kty, *,
     (row-side leaves [k, Wr, M] / [k, Ww, Dr] / [k, Dr]); x/c/l/u/kty:
     [k, N]; tau: [k] (per-sub-problem step size — POP sub-problems restart
     independently, so step sizes diverge across the batch)."""
-    k, wr, M = s.row_idx.shape
-    N = x.shape[1]
-    out = [jax.ShapeDtypeStruct((k, N), jnp.float32),
-           jax.ShapeDtypeStruct((k, M), jnp.float32)]
-    return pl.pallas_call(
+    return _lane_call(
         _forward_kernel,
-        grid=(k,),
-        in_specs=[
-            _vec((1,) + s.row_idx.shape[1:]),
-            _vec((1,) + s.row_val.shape[1:]),
-            _vec((1,) + s.wrow_idx.shape[1:]),
-            _vec((1,) + s.wrow_val.shape[1:]),
-            _vec((1,) + s.wrow_ids.shape[1:]),
-            _vec((1, N)), _vec((1, N)), _vec((1, N)), _vec((1, N)),
-            _vec((1, N)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[_vec((1, N)), _vec((1, M))],
-        out_shape=out,
-        interpret=interpret,
-    )(s.row_idx, s.row_val, s.wrow_idx, s.wrow_val, s.wrow_ids,
-      x, c, l, u, kty, tau[:, None])
+        (s.row_idx, s.row_val, s.wrow_idx, s.wrow_val, s.wrow_ids),
+        (x, c, l, u, kty), tau, s.row_idx.shape[-1], interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -143,30 +178,11 @@ def structured_backward_step(s, y, q, ineq_mask, kx_new, kx_prev, sigma, *,
     """Returns (y_new, kty).  ``s`` carries the column-side leaves
     ([k, Wc, N] / [k, Wv, Dc] / [k, Dc]); y/q/ineq_mask/kx_new/kx_prev:
     [k, M]; sigma: [k]."""
-    k, wc, N = s.col_idx.shape
-    M = y.shape[1]
-    out = [jax.ShapeDtypeStruct((k, M), jnp.float32),
-           jax.ShapeDtypeStruct((k, N), jnp.float32)]
-    return pl.pallas_call(
+    return _lane_call(
         _backward_kernel,
-        grid=(k,),
-        in_specs=[
-            _vec((1,) + s.col_idx.shape[1:]),
-            _vec((1,) + s.col_val.shape[1:]),
-            _vec((1,) + s.wcol_idx.shape[1:]),
-            _vec((1,) + s.wcol_val.shape[1:]),
-            _vec((1,) + s.wcol_ids.shape[1:]),
-            _vec((1, M)), _vec((1, M)), _vec((1, M)), _vec((1, M)),
-            _vec((1, M)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[_vec((1, M)), _vec((1, N))],
-        out_shape=out,
-        interpret=interpret,
-    )(s.col_idx, s.col_val, s.wcol_idx, s.wcol_val, s.wcol_ids,
-      y, q, ineq_mask, kx_new, kx_prev, sigma[:, None])
-
-
+        (s.col_idx, s.col_val, s.wcol_idx, s.wcol_val, s.wcol_ids),
+        (y, q, ineq_mask, kx_new, kx_prev), sigma, s.col_idx.shape[-1],
+        interpret)
 # --------------------------------------------------------------------------
 # M-blocked streaming family: the single-lane FULL problem
 # --------------------------------------------------------------------------

@@ -17,7 +17,7 @@ from ..configs import get_config
 from ..launch import specs as sp
 from ..launch import shardings as sh
 from ..launch.dryrun import probe_costs
-from ..launch.mesh import make_production_mesh
+from ..launch.mesh import make_auto_mesh, make_production_mesh
 from ..launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS, fmt_s
 
 
@@ -26,7 +26,7 @@ def measure(arch: str, shape: str, flags: dict, mesh_shape=None) -> dict:
     cfg = get_config(arch)
     cell = sp.SHAPES[shape]
     if mesh_shape is not None:
-        mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+        mesh = make_auto_mesh(mesh_shape, ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=False)
     n_dp = int(np.prod([mesh.shape[a] for a in sh.dp_axes(mesh)]))

@@ -94,7 +94,8 @@ def _k_mv(data, x):
     t = x[C * R]
 
     # per-(combo, slot) scaled throughput contribution
-    contrib = jnp.einsum("crs,cr->cs", S, X)              # [C, 2]
+    contrib = jnp.einsum("crs,cr->cs", S, X,
+                         precision=jax.lax.Precision.HIGHEST)  # [C, 2]
     thpt = jax.ops.segment_sum(contrib.reshape(-1), member.reshape(-1),
                                num_segments=n_jobs + 1)[:n_jobs]
     # time: each combo occurrence consumes the member's time budget
@@ -120,7 +121,8 @@ def _kt_mv(data, y):
     ep_m = y_ep_pad[member]                               # [C, 2]
     tm_m = y_time_pad[member]                             # [C, 2]
 
-    gX = (-jnp.einsum("crs,cs->cr", S, ep_m)
+    gX = (-jnp.einsum("crs,cs->cr", S, ep_m,
+                      precision=jax.lax.Precision.HIGHEST)
           + tm_m.sum(axis=1)[:, None]
           + z[:, None] * y_work[None, :])
     gt = y_ep.sum()

@@ -102,8 +102,9 @@ def _k_mv(data, x):
     n = l.shape[0]
     S = _cost.shape[1]
     X = x.reshape(n, S)
-    load = X.T @ l                       # [S]
-    mem = X.T @ m                        # [S]
+    # full f32 products: the TPU's default f32 matmul rounds to bf16
+    load = jnp.dot(l, X, precision=jax.lax.Precision.HIGHEST)    # [S]
+    mem = jnp.dot(m, X, precision=jax.lax.Precision.HIGHEST)     # [S]
     one = X.sum(axis=1)                  # [n]
     return jnp.concatenate([load, -load, mem, one])
 
@@ -381,6 +382,7 @@ class LoadBalanceProblem:
         # observability: what actually ran ("auto" resolved) + plan cache
         ev["backend"] = backend_name
         ev["engine"] = engine_name
+        ev["kernels"] = pdhg.engine_kernels(engine_name)
         ev["plan_cache"] = "full"
         ev["k"] = 1
         return LBResult(placement=placement, movement=ev["movement"],
@@ -519,6 +521,7 @@ class LoadBalanceProblem:
         # shard churn, "miss" = fresh grouping)
         ev["backend"] = backend_name
         ev["engine"] = pdhg.engine_name(engine_run)
+        ev["kernels"] = pdhg.engine_kernels(engine_run)
         ev["plan_cache"] = ("hit" if reuse
                             else "repair" if grouping_kept else "miss")
         ev["k"] = k

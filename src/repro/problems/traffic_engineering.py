@@ -107,21 +107,31 @@ def k_shortest_paths(topo: Topology, pairs: np.ndarray, n_paths: int = 4,
     rng = np.random.default_rng(seed)
     E = topo.edges.shape[0]
     srcs = np.unique(pairs[:, 0])
+    row_of = np.zeros(topo.n_nodes, np.int64)
+    row_of[srcs] = np.arange(srcs.shape[0])
+    s = pairs[:, 0].astype(np.int64)
     out = np.full((pairs.shape[0], n_paths, max_len), -1, np.int64)
     for draw in range(n_paths):
         jitter = (np.ones(E) if draw == 0
                   else rng.uniform(1.0, 1.0 + 0.6 * draw, E))
-        trees = {int(s): _dijkstra_tree(topo, int(s), jitter) for s in srcs}
-        for j, (s, t) in enumerate(pairs):
-            prev = trees[int(s)]
-            path = []
-            node = int(t)
-            while node != int(s) and prev[node] >= 0 and len(path) < max_len:
-                e = prev[node]
-                path.append(e)
-                node = int(topo.edges[e, 0])
-            if node == int(s):
-                out[j, draw, : len(path)] = path[::-1]
+        prev = np.stack([_dijkstra_tree(topo, int(src), jitter)
+                         for src in srcs])            # [n_srcs, n_nodes]
+        # walk every demand back from its target to its source at once
+        node = pairs[:, 1].astype(np.int64)
+        rev = np.full((pairs.shape[0], max_len), -1, np.int64)
+        length = np.zeros(pairs.shape[0], np.int64)
+        for step in range(max_len):
+            e = prev[row_of[s], node]
+            walk = (node != s) & (e >= 0)
+            if not walk.any():
+                break
+            rev[walk, step] = e[walk]
+            length += walk
+            node = np.where(walk, topo.edges[np.maximum(e, 0), 0], node)
+        # paths that reached their source, in source-to-target order
+        back = length[:, None] - 1 - np.arange(max_len)[None, :]
+        fwd = np.take_along_axis(rev, np.maximum(back, 0), axis=1)
+        out[:, draw] = np.where((back >= 0) & (node == s)[:, None], fwd, -1)
     return out
 
 
@@ -258,6 +268,27 @@ class TrafficProblem(POPProblem):
     def extract(self, op: OperatorLP, x: np.ndarray, idx_row: np.ndarray):
         P = self.path_edges.shape[1]
         return x[: idx_row.shape[0] * P].reshape(-1, P)
+
+    def fit_to_capacity(self, f: np.ndarray) -> np.ndarray:
+        """The flows scaled down until every demand and link constraint
+        holds exactly.  PDHG stops at a relative residual, so a solved
+        allocation can overdraw a few links by about that much; each
+        demand is first cut to its size, then each path's flow by the
+        tightest ``capacity / load`` ratio along it (loads only fall, so
+        one pass suffices).  f: [n, P] in global entity order."""
+        f = np.maximum(np.asarray(f, np.float64), 0.0)
+        tot = f.sum(axis=1, keepdims=True)
+        f = f * np.minimum(1.0, self.demand[:, None]
+                           / np.maximum(tot, 1e-300))
+        E = self.topo.edges.shape[0]
+        pe = np.where(self.path_edges >= 0, self.path_edges, E)
+        load = np.zeros(E + 1)
+        np.add.at(load, pe.reshape(-1),
+                  np.broadcast_to(f[:, :, None], pe.shape).reshape(-1))
+        ratio = np.append(
+            np.minimum(1.0, self.topo.capacity / np.maximum(load[:E], 1e-300)),
+            1.0)
+        return f * ratio[pe].min(axis=2)
 
     def evaluate(self, f: np.ndarray) -> dict:
         """f: [n, P] per-path flows in GLOBAL entity order."""

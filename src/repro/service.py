@@ -161,6 +161,9 @@ class Allocation:
     raw: Any = None
     status: str = "ok"
     faults: tuple = ()
+    # what the engine's inner loop ran as: "pallas" (compiled kernels),
+    # "interpret" or "xla" (see kernels/ops.kernel_mode); None if no solve
+    kernels: Optional[str] = None
 
     @property
     def objective(self) -> Optional[float]:
@@ -656,7 +659,8 @@ class PopSession:
         self._warm, self._mode = out.warm_state, "domain"
         return self._wrap(
             instance, out.alloc, out.metrics, backend=out.backend,
-            engine=out.engine, plan_cache=out.plan_cache, k=out.k,
+            engine=out.engine, kernels=out.kernels,
+            plan_cache=out.plan_cache, k=out.k,
             warm_fraction=out.warm_fraction,
             solve_time_s=out.solve_time_s,
             build_time_s=out.build_time_s,
@@ -753,7 +757,8 @@ class PopSession:
         wf = res.warm_stats["warm_fraction"] if res.warm_stats else None
         return self._wrap(
             instance, res.alloc, None, problem=problem,
-            backend=res.backend, engine=res.engine, plan_cache=cache,
+            backend=res.backend, engine=res.engine, kernels=res.kernels,
+            plan_cache=cache,
             k=res.plan.k if res.plan is not None else 0,
             warm_fraction=wf, solve_time_s=res.solve_time_s,
             build_time_s=res.build_time_s,
@@ -813,7 +818,7 @@ class PopSession:
                         fr.solve_time_s, time.perf_counter() - t0)
         return self._wrap(
             instance, fr.alloc, None, problem=problem, backend=fr.backend,
-            engine=fr.engine, plan_cache="full", k=1,
+            engine=fr.engine, kernels=fr.kernels, plan_cache="full", k=1,
             warm_fraction=None if warm is None else 1.0,
             solve_time_s=fr.solve_time_s, build_time_s=fr.build_time_s,
             iterations=int(np.asarray(fr.res.iterations).sum()), raw=fr,
@@ -939,9 +944,9 @@ class PopSession:
             faults=tuple(faults) if faults else ("deadline",))
 
     def _wrap(self, instance, raw_alloc, metrics, *, backend, engine,
-              plan_cache, k, warm_fraction, solve_time_s, build_time_s=0.0,
-              iterations=0, raw=None, problem=None, status="ok",
-              faults=()) -> Allocation:
+              kernels, plan_cache, k, warm_fraction, solve_time_s,
+              build_time_s=0.0, iterations=0, raw=None, problem=None,
+              status="ok", faults=()) -> Allocation:
         alloc = raw_alloc
         if self.spec.round is not None and self.spec.step_override is None:
             alloc = self.spec.round(instance, raw_alloc)
@@ -953,7 +958,7 @@ class PopSession:
             plan_cache=plan_cache, k=k, warm_fraction=warm_fraction,
             solve_time_s=solve_time_s, build_time_s=build_time_s,
             iterations=iterations, raw=raw, status=status,
-            faults=tuple(faults))
+            faults=tuple(faults), kernels=kernels)
 
     # ------------------------------------------------------ checkpoint hooks --
     def _checkpoint_payload(self, prefix: str):

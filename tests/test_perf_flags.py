@@ -23,7 +23,8 @@ SCRIPT = textwrap.dedent("""
     from repro.serve.engine import ServeConfig, make_serve_step
     from repro.models import init_cache, init_params
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_auto_mesh
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
 
     # --- train: sp_residual transparency --------------------------------
     cfg = get_reduced("gemma3_4b")
