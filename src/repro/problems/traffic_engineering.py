@@ -269,27 +269,6 @@ class TrafficProblem(POPProblem):
         P = self.path_edges.shape[1]
         return x[: idx_row.shape[0] * P].reshape(-1, P)
 
-    def fit_to_capacity(self, f: np.ndarray) -> np.ndarray:
-        """The flows scaled down until every demand and link constraint
-        holds exactly.  PDHG stops at a relative residual, so a solved
-        allocation can overdraw a few links by about that much; each
-        demand is first cut to its size, then each path's flow by the
-        tightest ``capacity / load`` ratio along it (loads only fall, so
-        one pass suffices).  f: [n, P] in global entity order."""
-        f = np.maximum(np.asarray(f, np.float64), 0.0)
-        tot = f.sum(axis=1, keepdims=True)
-        f = f * np.minimum(1.0, self.demand[:, None]
-                           / np.maximum(tot, 1e-300))
-        E = self.topo.edges.shape[0]
-        pe = np.where(self.path_edges >= 0, self.path_edges, E)
-        load = np.zeros(E + 1)
-        np.add.at(load, pe.reshape(-1),
-                  np.broadcast_to(f[:, :, None], pe.shape).reshape(-1))
-        ratio = np.append(
-            np.minimum(1.0, self.topo.capacity / np.maximum(load[:E], 1e-300)),
-            1.0)
-        return f * ratio[pe].min(axis=2)
-
     def evaluate(self, f: np.ndarray) -> dict:
         """f: [n, P] per-path flows in GLOBAL entity order."""
         flow = f.sum(axis=1)

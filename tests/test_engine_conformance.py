@@ -163,25 +163,6 @@ def test_auto_picks_structured_when_metadata_present(cells):
         pdhg.resolve_engine("fused_structured", bare)
 
 
-@pytest.mark.parametrize("dense_max_bytes,want", [(2 << 30, "fused"),
-                                                  (1024, "fused_structured")])
-def test_auto_densifies_small_structured_stacks_on_tpu(
-        cells, monkeypatch, dense_max_bytes, want):
-    """On a TPU the ELL gathers run as slow XLA gathers, so a structured
-    stack whose dense K fits ``DENSE_MAX_BYTES`` takes the dense engine,
-    densified once per solve; larger stacks keep the gather engine."""
-    ops, _, k_mv, kt_mv, ref = cells["traffic"]
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(pdhg, "DENSE_MAX_BYTES", dense_max_bytes)
-    assert pdhg.select_engine(ops, k_mv, kt_mv) == want
-    monkeypatch.undo()
-    if want == "fused":
-        r = backends_mod.solve_map(ops, k_mv, kt_mv, FIXED_KW,
-                                   backend="vmap", engine="fused")
-        np.testing.assert_allclose(np.asarray(r.x), np.asarray(ref.x),
-                                   rtol=1e-5, atol=1e-5)
-
-
 def test_conformance_multi_device_subprocess():
     """Ragged k on a real multi-device mesh: k=3 on a forced 4-device host
     pads to 4 lanes in shard_map/pmap; the structured engine must ride the
@@ -284,7 +265,7 @@ def test_unknown_kkt_mode_rejected():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("backend", BACKENDS)
-@pytest.mark.parametrize("engine", ("matvec", "fused", "fused_structured"))
+@pytest.mark.parametrize("engine", ("matvec", "fused_structured"))
 def test_reported_execution_matches_forced_cell(backend, engine):
     """Every forced (engine x backend) cell must come back on the
     POPResult verbatim — the resolution layer may not silently substitute."""

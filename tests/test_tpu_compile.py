@@ -28,8 +28,7 @@ V5E_HBM_BYTES = 16 * 10**9
 LANE = dict(k=64, m=11_393, n=31_252, wr=32, ww=3_440, dr=2_118,
             wc=56, wv=8, dc=1)
 # per 128-column chunk of the descending-width row bucket, the chunk's
-# width over all lanes (``core/pdhg._wide_block_plan``); the column
-# bucket is below ``pdhg.LANE_PLAN_MIN_WIDE_ELEMS`` and gets no plan
+# width over all lanes (``core/pdhg._wide_block_plan``)
 LANE_ROW_PLAN_WIDTHS = (3_440, 984, 672, 504, 392, 312, 256, 208, 168, 144,
                         120, 96, 80, 72, 56, 48, 40)
 # the unpartitioned problem (k=1)
@@ -122,7 +121,8 @@ def test_lane_half_step_compiles(chip, direction):
         plan = _plan(LANE["dr"], LANE_ROW_PLAN_WIDTHS)
         fn = lambda *a: ops.structured_forward_step(*a, plan=plan)
     else:
-        fn = ops.structured_backward_step
+        plan = ((0, LANE["dc"], LANE["wv"]),)
+        fn = lambda *a: ops.structured_backward_step(*a, plan=plan)
     _compile(fn, *_half_step_args(chip, LANE, direction))
 
 
@@ -139,9 +139,8 @@ def test_full_half_step_compiles(chip, direction):
     _compile(fn, *_half_step_args(chip, FULL, direction))
 
 
-# (k, M, N) of the traffic stacks a TPU densifies onto the dense engine
-# (``pdhg.DENSE_MAX_BYTES``): 3,000 demands unpartitioned, and 20,000
-# demands at k=64, on the same topology
+# (k, M, N) of dense operators the size of the traffic LPs: 3,000
+# demands unpartitioned, and 20,000 demands at k=64, on the same topology
 DENSE = [(1, 6_580, 12_000), (64, 3_893, 1_252)]
 
 
@@ -150,9 +149,9 @@ DENSE = [(1, 6_580, 12_000), (64, 3_893, 1_252)]
                                     "fused_backward_step", "bmatvec",
                                     "bmatvec_t"])
 def test_dense_kernel_compiles(chip, kernel, k, m, n):
-    """The dense engine's Pallas kernels (``fused``, which a TPU also
-    picks for small structured stacks; its K/K^T products outside the
-    loop), compiled as Mosaic kernels: what a TPU runs."""
+    """The dense engine's Pallas kernels (``fused``, which a TPU picks for
+    dense operators; its K/K^T products outside the loop), compiled as
+    Mosaic kernels: what a TPU runs."""
     A = _spec(chip, (k, m, n))
     if kernel == "fused_forward_step":
         args = (A, *_vectors(chip, k, n, 4), _spec(chip, (k,)),
