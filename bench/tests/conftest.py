@@ -1,0 +1,9 @@
+"""Puts ``bench/`` on the path, so the tests import the harness as
+``bench/run.py`` does."""
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
