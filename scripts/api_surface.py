@@ -36,6 +36,7 @@ PUBLIC_MODULES = [
     "repro.serve",
     "repro.sched",
     "repro.tuning",
+    "repro.obs",
 ]
 
 _ADDR = re.compile(r" at 0x[0-9a-fA-F]+")

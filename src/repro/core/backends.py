@@ -190,19 +190,27 @@ def _cached_solver(K_mv, KT_mv, kw_items, engine):
 
 
 def _build_solver(K_mv, KT_mv, solver_kw: dict, engine: EngineSpec):
+    """The map solver ``fn(batch)``.  Its name (``map_solve_matvec`` /
+    ``map_solve_stacked``) names the XLA module in a profiler trace."""
     if engine == "matvec":
         # vmap over per-lane k=1 solves (pdhg.solve IS solve_stacked at
         # k=1 — same loop) rather than one native k-stack: per-lane XLA
         # numerics are then independent of the batch size, which is what
         # lets serial/chunked/shard_map/pmap match vmap bit-for-bit.
         sol = functools.partial(pdhg.solve, K_mv=K_mv, KT_mv=KT_mv, **solver_kw)
-        return lambda batch: jax.vmap(
-            lambda o, wx, wy: sol(o, warm_x=wx, warm_y=wy))(*batch)
+
+        def map_solve_matvec(batch):
+            return jax.vmap(
+                lambda o, wx, wy: sol(o, warm_x=wx, warm_y=wy))(*batch)
+        return map_solve_matvec
     if not isinstance(engine, StepEngine):
         raise ValueError(f"unresolved engine {engine!r} reached a backend; "
                          "go through solve_map or pass a StepEngine")
-    return lambda batch: pdhg.solve_stacked(
-        batch[0], engine=engine, warm_x=batch[1], warm_y=batch[2], **solver_kw)
+
+    def map_solve_stacked(batch):
+        return pdhg.solve_stacked(batch[0], engine=engine, warm_x=batch[1],
+                                  warm_y=batch[2], **solver_kw)
+    return map_solve_stacked
 
 
 def make_map_solver(K_mv, KT_mv, solver_kw: Optional[dict] = None,
@@ -237,7 +245,9 @@ def make_map_solver(K_mv, KT_mv, solver_kw: Optional[dict] = None,
 @functools.lru_cache(maxsize=64)
 def _chunked_runner(inner):
     """jit(lax.map(inner)) over [n_chunks, chunk, ...] stacked chunks."""
-    return jax.jit(lambda chunks: jax.lax.map(inner, chunks))
+    def chunked_map_solve(chunks):
+        return jax.lax.map(inner, chunks)
+    return jax.jit(chunked_map_solve)
 
 
 @functools.lru_cache(maxsize=64)

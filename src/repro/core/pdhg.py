@@ -1047,6 +1047,21 @@ def _equilibrate(engine: StepEngine, op: OperatorLP,
     return d_r, d_c
 
 
+def _scoped(name: str):
+    """Decorator: each call runs under a fresh ``jax.named_scope(name)``,
+    which names the operations it stages in the compiled program (a scope
+    object used as a decorator itself keeps its state on the one object,
+    which threads tracing at once would share)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return scoped
+    return deco
+
+
+@_scoped("pdhg.solve_stacked")
 def solve_stacked(
     op: OperatorLP,
     engine: Union[None, str, StepEngine] = None,
@@ -1136,25 +1151,8 @@ def solve_stacked(
     kx0 = eng_run.K(op_run.data, x0)
     kty0 = eng_run.KT(op_run.data, y0)
 
-    def chunk(state: _State) -> _State:
-        tau = eta / (state.omega * knorm)          # [k]
-        sigma = eta * state.omega / knorm          # [k]
-
-        def one_iter(_, carry):
-            x, y, kx, kty, xs, ys, kxs, ktys = carry
-            x_new, kx_new = eng_run.forward(op_run.data, x, op_run.c,
-                                            op_run.l, op_run.u, tau, kty)
-            y_new, kty_new = eng_run.backward(op_run.data, y, op_run.q,
-                                              sigma, op_run.ineq_mask,
-                                              kx_new, kx)
-            return (x_new, y_new, kx_new, kty_new,
-                    xs + x_new, ys + y_new, kxs + kx_new, ktys + kty_new)
-
-        x, y, kx, kty, xs, ys, kxs, ktys = jax.lax.fori_loop(
-            0, check_every, one_iter,
-            (state.x, state.y, state.kx, state.kty,
-             state.x_sum, state.y_sum, state.kx_sum, state.kty_sum),
-        )
+    def check(state: _State, x, y, kx, kty, xs, ys, kxs, ktys) -> _State:
+        """Convergence check and restart after a chunk of iterations."""
         avg_n = state.avg_n + check_every
 
         # ---- candidate = better of {current, running average} ------------
@@ -1246,6 +1244,30 @@ def solve_stacked(
             diverged=diverged,
         )
 
+    def chunk(state: _State) -> _State:
+        tau = eta / (state.omega * knorm)          # [k]
+        sigma = eta * state.omega / knorm          # [k]
+
+        def one_iter(_, carry):
+            x, y, kx, kty, xs, ys, kxs, ktys = carry
+            with jax.named_scope("pdhg.forward"):
+                x_new, kx_new = eng_run.forward(op_run.data, x, op_run.c,
+                                                op_run.l, op_run.u, tau, kty)
+            with jax.named_scope("pdhg.backward"):
+                y_new, kty_new = eng_run.backward(op_run.data, y, op_run.q,
+                                                  sigma, op_run.ineq_mask,
+                                                  kx_new, kx)
+            return (x_new, y_new, kx_new, kty_new,
+                    xs + x_new, ys + y_new, kxs + kx_new, ktys + kty_new)
+
+        x, y, kx, kty, xs, ys, kxs, ktys = jax.lax.fori_loop(
+            0, check_every, one_iter,
+            (state.x, state.y, state.kx, state.kty,
+             state.x_sum, state.y_sum, state.kx_sum, state.kty_sum),
+        )
+        with jax.named_scope("pdhg.check"):
+            return check(state, x, y, kx, kty, xs, ys, kxs, ktys)
+
     init = _State(
         x=x0, y=y0, kx=kx0, kty=kty0,
         x_sum=jnp.zeros_like(x0), y_sum=jnp.zeros_like(y0),
@@ -1271,7 +1293,8 @@ def solve_stacked(
     if equilibrate:
         # report in ORIGINAL space
         x_fin, y_fin = unscale_solution(x_fin, y_fin, d_r, d_c)
-    pr, gap, p_obj, d_obj = _kkt(op, eng, x_fin, y_fin)
+    with jax.named_scope("pdhg.kkt"):
+        pr, gap, p_obj, d_obj = _kkt(op, eng, x_fin, y_fin)
     return SolveResult(
         x=x_fin, y=y_fin, primal_obj=p_obj, dual_obj=d_obj,
         primal_res=pr, gap=gap, iterations=state.it, converged=state.done,

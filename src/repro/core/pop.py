@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import backends as backends_mod
 from . import partition as part_mod
 from . import pdhg
@@ -434,74 +435,80 @@ def prepare_instance(
     ids_agree = (prev_plan is None
                  or (prev_plan.entity_ids is None) == (entity_ids is None))
     source = "fresh"
-    if plan is not None:
-        p = plan
-        source = "provided"
-    elif (warm is not None and prev_plan is not None and not replan
-          and partition_idx is None
-          and solve_cfg.replicate_threshold is None and ids_agree):
-        if _plan_fits(prev_plan, problem, k, entity_ids):
-            p = prev_plan
-            source = "reused"
-        elif prev_plan.k == k and prev_plan.replication is None:
-            # entity churn at the same k: repair the old plan in place —
-            # survivors keep their (lane, slot), so the remapped warm start
-            # lands in an unchanged lane context (see plan.repair_plan)
-            p = repair_plan(prev_plan, problem, entity_ids=entity_ids)
-            source = "repaired"
+    with obs.span("pop.plan") as plan_span:
+        if plan is not None:
+            p = plan
+            source = "provided"
+        elif (warm is not None and prev_plan is not None and not replan
+              and partition_idx is None
+              and solve_cfg.replicate_threshold is None and ids_agree):
+            if _plan_fits(prev_plan, problem, k, entity_ids):
+                p = prev_plan
+                source = "reused"
+            elif prev_plan.k == k and prev_plan.replication is None:
+                # entity churn at the same k: repair the old plan in place
+                # — survivors keep their (lane, slot), so the remapped warm
+                # start lands in an unchanged lane context (see
+                # plan.repair_plan)
+                p = repair_plan(prev_plan, problem, entity_ids=entity_ids)
+                source = "repaired"
+            else:
+                p = make_plan(problem, k, strategy=solve_cfg.strategy,
+                              seed=solve_cfg.seed, entity_ids=entity_ids)
         else:
             p = make_plan(problem, k, strategy=solve_cfg.strategy,
-                          seed=solve_cfg.seed, entity_ids=entity_ids)
-    else:
-        p = make_plan(problem, k, strategy=solve_cfg.strategy,
-                      seed=solve_cfg.seed,
-                      replicate_threshold=solve_cfg.replicate_threshold,
-                      partition_idx=partition_idx, entity_ids=entity_ids)
-    ops = build(problem, p)
-    _require_finite_ops(ops, "solve_instance")
+                          seed=solve_cfg.seed,
+                          replicate_threshold=solve_cfg.replicate_threshold,
+                          partition_idx=partition_idx, entity_ids=entity_ids)
+        plan_span.set(source=source)
+    with obs.span("pop.build"):
+        ops = build(problem, p)
+        _require_finite_ops(ops, "solve_instance")
     build_time = time.perf_counter() - t0
 
-    warm_in = None
-    warm_stats = None
-    if warm is not None:
-        if source == "reused":
-            # identity churn: the PR-2 path, previous iterates verbatim
-            warm_in = (warm.x, warm.y)
-            n_live = int((p.entity_of_slot >= 0).sum())
-            warm_stats = dict(warm_fraction=1.0, matched=n_live, fresh=0,
-                              dropped=0, lanes_cold=0, identity=True)
-        elif not ids_agree:
-            warm_stats = dict(warm_fraction=0.0, matched=0, fresh=0,
-                              dropped=0, lanes_cold=k, identity=False,
-                              reason="entity id spaces differ (one side has "
-                                     "entity_ids, the other is positional)")
-        elif prev_plan is not None:
-            ws = remap_warm(prev_plan, p, warm, ops=ops)
-            warm_in = ws
-            warm_stats = ws.stats
+    with obs.span("pop.remap"):
+        warm_in = None
+        warm_stats = None
+        if warm is not None:
+            if source == "reused":
+                # identity churn: the PR-2 path, previous iterates verbatim
+                warm_in = (warm.x, warm.y)
+                n_live = int((p.entity_of_slot >= 0).sum())
+                warm_stats = dict(warm_fraction=1.0, matched=n_live, fresh=0,
+                                  dropped=0, lanes_cold=0, identity=True)
+            elif not ids_agree:
+                warm_stats = dict(
+                    warm_fraction=0.0, matched=0, fresh=0, dropped=0,
+                    lanes_cold=k, identity=False,
+                    reason="entity id spaces differ (one side has "
+                           "entity_ids, the other is positional)")
+            elif prev_plan is not None:
+                ws = remap_warm(prev_plan, p, warm, ops=ops)
+                warm_in = ws
+                warm_stats = ws.stats
 
-    if cold_lanes is not None and warm_in is not None:
-        # divergence quarantine: poisoned lanes restart cold, survivors
-        # keep their iterates (a data-level mask — same jit cache key)
-        cl = np.asarray(cold_lanes, bool).reshape(-1)
-        if cl.shape[0] != p.k:
-            raise ValueError(f"cold_lanes has {cl.shape[0]} entries for "
-                             f"k={p.k} lanes")
-        if isinstance(warm_in, WarmStart):
-            wx, wy = warm_in.x, warm_in.y
-            mask = np.asarray(warm_in.mask, bool) & ~cl
-            stats = dict(warm_in.stats or {})
-        else:
-            wx, wy = warm_in
-            mask = ~cl
-            stats = dict(warm_stats or {})
-        stats["quarantined_lanes"] = int(cl.sum())
-        stats["lanes_cold"] = int((~mask).sum())
-        stats["warm_fraction"] = float(
-            stats.get("warm_fraction", 1.0) * mask.mean()) if p.k else 0.0
-        stats["identity"] = False
-        warm_in = WarmStart(x=wx, y=wy, mask=mask, stats=stats)
-        warm_stats = stats
+        if cold_lanes is not None and warm_in is not None:
+            # divergence quarantine: poisoned lanes restart cold, survivors
+            # keep their iterates (a data-level mask — same jit cache key)
+            cl = np.asarray(cold_lanes, bool).reshape(-1)
+            if cl.shape[0] != p.k:
+                raise ValueError(f"cold_lanes has {cl.shape[0]} entries for "
+                                 f"k={p.k} lanes")
+            if isinstance(warm_in, WarmStart):
+                wx, wy = warm_in.x, warm_in.y
+                mask = np.asarray(warm_in.mask, bool) & ~cl
+                stats = dict(warm_in.stats or {})
+            else:
+                wx, wy = warm_in
+                mask = ~cl
+                stats = dict(warm_stats or {})
+            stats["quarantined_lanes"] = int(cl.sum())
+            stats["lanes_cold"] = int((~mask).sum())
+            stats["warm_fraction"] = float(
+                stats.get("warm_fraction", 1.0) * mask.mean()) if p.k else 0.0
+            stats["identity"] = False
+            warm_in = WarmStart(x=wx, y=wy, mask=mask, stats=stats)
+            warm_stats = stats
 
     # resolve "auto" specs HERE so the result can report what actually ran
     backend_name, engine_run, opts = backends_mod.resolve_exec(
@@ -658,8 +665,9 @@ def prepare_full(problem: POPProblem, *,
     exec_cfg = exec_cfg or ExecConfig()
     solver_kw = exec_cfg.solver_dict()
     t0 = time.perf_counter()
-    op = problem.build_full()
-    _require_finite_ops(op, "solve_full_ex")
+    with obs.span("pop.build"):
+        op = problem.build_full()
+        _require_finite_ops(op, "solve_full_ex")
     build_time = time.perf_counter() - t0
     opb = jax.tree.map(lambda a: jnp.asarray(a)[None], op)
     backend_name, engine_run, opts = backends_mod.resolve_exec(

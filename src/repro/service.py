@@ -74,6 +74,7 @@ from typing import Any, Dict, Optional, Union
 import jax
 import numpy as np
 
+from . import obs
 from .checkpoint import paged as paged_mod
 from .checkpoint import session_state as ckpt_mod
 from .core import backends as backends_mod
@@ -235,6 +236,11 @@ def _pop_warm_ok(warm) -> bool:
     return True
 
 
+# the (request id, submit perf_counter_ns) that step_async hands to the
+# step() its pool worker runs next
+_submitted = threading.local()
+
+
 def _count_diverged(res) -> int:
     div = getattr(res, "diverged", None)
     return 0 if div is None else int(np.asarray(div).sum())
@@ -267,9 +273,12 @@ class DispatchConfig:
 
 
 class _Ticket:
-    """One tenant's prepared map-step launch, queued for dispatch."""
+    """One tenant's prepared map-step launch, queued for dispatch, with
+    the request and span it serves and the ``perf_counter_ns`` of its
+    ``put`` (the start of its ``pop.queue`` interval)."""
 
-    __slots__ = ("key", "batch", "prep", "K_mv", "KT_mv", "future")
+    __slots__ = ("key", "batch", "prep", "K_mv", "KT_mv", "future", "rid",
+                 "parent", "put_ns")
 
     def __init__(self, key, batch, prep, K_mv, KT_mv, future):
         self.key = key
@@ -278,6 +287,8 @@ class _Ticket:
         self.K_mv = K_mv
         self.KT_mv = KT_mv
         self.future = future
+        self.parent, self.rid = obs.current()
+        self.put_ns = time.perf_counter_ns()
 
 
 class MicroBatchDispatcher:
@@ -297,7 +308,13 @@ class MicroBatchDispatcher:
 
     A failed group launch falls back to per-ticket solo launches, so one
     tenant's pathological batch cannot fail its peers — only its own
-    caller sees the exception (which the session ladder then handles)."""
+    caller sees the exception (which the session ladder then handles).
+
+    Spans (``repro.obs``): ``pop.batch`` on the calling thread; on the
+    dispatcher thread ``pop.queue`` per ticket (its ``put`` to the start
+    of the launch that serves it), ``pop.launch`` (concat, pad, launch,
+    split; its ``rids`` attribute names every request served) and, inside
+    it, ``pop.device`` (waiting for the device's results)."""
 
     def __init__(self, cfg: Optional[DispatchConfig] = None):
         self.cfg = cfg or DispatchConfig()
@@ -309,7 +326,9 @@ class MicroBatchDispatcher:
         self._counts = {
             "requests": 0, "launches": 0, "lanes": 0,
             "coalesced_launches": 0, "coalesced_requests": 0,
-            "solo_launches": 0, "group_fallbacks": 0, "max_group": 0}
+            "solo_launches": 0, "group_fallbacks": 0, "max_group": 0,
+            # seconds, the totals of the pop.queue and pop.device spans
+            "queue_wait_s": 0.0, "device_wait_s": 0.0}
         self._thread = threading.Thread(target=self._loop,
                                         name="pop-dispatch", daemon=True)
         self._thread.start()
@@ -322,16 +341,20 @@ class MicroBatchDispatcher:
         tenant's lane-weighted share of the launch wall time.
         Launches that cannot share (single-lane streaming engine,
         unhashable configs) run inline on the calling thread."""
-        batch = backends_mod.make_batch(prep.ops, prep.warm)
-        key = backends_mod.coalesce_key(prep.ops, K_mv, KT_mv, prep.backend,
-                                        prep.engine, prep.solver_kw,
-                                        prep.opts)
+        with obs.span("pop.batch"):
+            batch = backends_mod.make_batch(prep.ops, prep.warm)
+            key = backends_mod.coalesce_key(prep.ops, K_mv, KT_mv,
+                                            prep.backend, prep.engine,
+                                            prep.solver_kw, prep.opts)
         with self._lock:
             self._counts["requests"] += 1
         if key is None or not self._thread.is_alive():
             tk = _Ticket(None, batch, prep, K_mv, KT_mv, None)
             t1 = time.perf_counter()
-            res = self._launch(batch, tk)
+            with obs.span("pop.launch", cpu=True,
+                          lanes=backends_mod.batch_size(batch), group=1,
+                          rids=(tk.rid,)):
+                res = self._launch(batch, tk)
             wall = time.perf_counter() - t1
             with self._lock:
                 self._counts["launches"] += 1
@@ -437,21 +460,34 @@ class MicroBatchDispatcher:
         res = backends_mod.get_backend(prep.backend)(
             batch, tk.K_mv, tk.KT_mv, dict(prep.solver_kw),
             engine=prep.engine, **prep.opts)
-        jax.block_until_ready(res.x)
+        with obs.span("pop.device") as dev:
+            jax.block_until_ready(res.x)
+        with self._lock:
+            self._counts["device_wait_s"] += dev.dur_ns / 1e9
         return res
 
     def _run_group(self, grp: list) -> None:
+        start = time.perf_counter_ns()
+        for tk in grp:
+            obs.record("pop.queue", tk.put_ns, start, rid=tk.rid,
+                       parent=tk.parent)
+        with self._lock:
+            self._counts["queue_wait_s"] += sum(
+                start - tk.put_ns for tk in grp) / 1e9
         if len(grp) > 1:
             t1 = time.perf_counter()
             try:
-                batch, sizes = backends_mod.concat_batches(
-                    [t.batch for t in grp])
-                total = sum(sizes)
-                if self.cfg.pad_pow2:
-                    batch, _ = backends_mod.pad_lanes_pow2(batch)
-                res = self._launch(batch, grp[0])
-                res = jax.tree.map(lambda a: a[:total], res)
-                parts = backends_mod.split_result(res, sizes)
+                with obs.span("pop.launch", cpu=True, group=len(grp),
+                              rids=tuple(tk.rid for tk in grp)) as sp:
+                    batch, sizes = backends_mod.concat_batches(
+                        [t.batch for t in grp])
+                    total = sum(sizes)
+                    if self.cfg.pad_pow2:
+                        batch, _ = backends_mod.pad_lanes_pow2(batch)
+                    sp.set(lanes=backends_mod.batch_size(batch))
+                    res = self._launch(batch, grp[0])
+                    res = jax.tree.map(lambda a: a[:total], res)
+                    parts = backends_mod.split_result(res, sizes)
                 wall = time.perf_counter() - t1
                 with self._lock:
                     self._counts["launches"] += 1
@@ -472,7 +508,10 @@ class MicroBatchDispatcher:
         for tk in grp:
             t1 = time.perf_counter()
             try:
-                res = self._launch(tk.batch, tk)
+                with obs.span("pop.launch", cpu=True, rid=tk.rid,
+                              lanes=backends_mod.batch_size(tk.batch),
+                              group=1, rids=(tk.rid,)):
+                    res = self._launch(tk.batch, tk)
                 wall = time.perf_counter() - t1
                 with self._lock:
                     self._counts["launches"] += 1
@@ -597,23 +636,40 @@ class PopSession:
         down the ladder (docs/ROBUSTNESS.md) when the budget is short —
         the returned :class:`Allocation` reports the rung in ``status``.
         Without a deadline the fault-free path is byte-identical to the
-        pre-deadline behavior (same jit cache keys, zero retraces)."""
-        with self._lock:
-            self.service._reattach(self)
-            t0 = time.perf_counter()
-            if self.spec.step_override is not None:
-                alloc = self._step_override(instance, deadline_s, t0)
-            else:
-                alloc = self._step_generic(instance, deadline_s, t0)
-            self.steps += 1
-            self._last_wall = time.perf_counter() - t0
-            if self._tuner is not None and alloc.status != "fallback":
-                self._observe_tuned(alloc)
-            _tally(self.stats, alloc)
-            with self.service._lock:
-                _tally(self.service._stats, alloc)
-            self.last = alloc
-        self.service._after_step(self)
+        pre-deadline behavior (same jit cache keys, zero retraces).
+
+        The step runs under its root span ``pop.step`` (``repro.obs``),
+        as a new request, or as the one :meth:`step_async` submitted, from
+        its submit time."""
+        rid, submitted_ns = (getattr(_submitted, "request", None)
+                             or (obs.new_request(), None))
+        _submitted.request = None
+        with obs.span("pop.step", rid=rid, start_ns=submitted_ns,
+                      tenant=self.tenant) as root:
+            if submitted_ns is not None:
+                obs.record("pop.pool_wait", submitted_ns,
+                           time.perf_counter_ns())
+            with obs.span("pop.lock_wait"):
+                self._lock.acquire()
+            try:
+                self.service._reattach(self)
+                t0 = time.perf_counter()
+                if self.spec.step_override is not None:
+                    alloc = self._step_override(instance, deadline_s, t0)
+                else:
+                    alloc = self._step_generic(instance, deadline_s, t0)
+                self.steps += 1
+                self._last_wall = time.perf_counter() - t0
+                if self._tuner is not None and alloc.status != "fallback":
+                    self._observe_tuned(alloc)
+                _tally(self.stats, alloc)
+                with self.service._lock:
+                    _tally(self.service._stats, alloc)
+                self.last = alloc
+            finally:
+                self._lock.release()
+            self.service._after_step(self)
+            root.set(plan=alloc.plan_cache, status=alloc.status)
         return alloc
 
     def step_async(self, instance: Any, *,
@@ -624,8 +680,17 @@ class PopSession:
         session lock (warm chains stay ordered); steps of DIFFERENT
         sessions run concurrently, and when the service has a dispatcher
         their map-step launches coalesce into shared device launches."""
-        return self.service._submit(self.step, instance,
-                                    deadline_s=deadline_s)
+        request = (obs.new_request(), time.perf_counter_ns())
+        return self.service._submit(self._step_submitted, request, instance,
+                                    deadline_s)
+
+    def _step_submitted(self, request: tuple, instance: Any,
+                        deadline_s: Optional[float]) -> Allocation:
+        _submitted.request = request
+        try:
+            return self.step(instance, deadline_s=deadline_s)
+        finally:
+            _submitted.request = None
 
     # ------------------------------------------------- step_override domains --
     def _step_override(self, instance: Any, deadline_s: Optional[float],
@@ -641,8 +706,11 @@ class PopSession:
         attempts = [self._warm] + ([None] if self._warm is not None else [])
         for i, warm in enumerate(attempts):
             try:
-                cand: StepOutcome = self.spec.step_override(
-                    instance, self.solve_cfg, self.exec_cfg, warm)
+                # the domain runs its own pipeline: the service sees one
+                # stage, the solve
+                with obs.span("pop.dispatch"):
+                    cand: StepOutcome = self.spec.step_override(
+                        instance, self.solve_cfg, self.exec_cfg, warm)
             except Exception as e:
                 faults.append(f"step-error:{type(e).__name__}")
                 continue
@@ -671,21 +739,27 @@ class PopSession:
     def _step_generic(self, instance: Any, deadline_s: Optional[float],
                       t0: float) -> Allocation:
         spec = self.spec
-        problem = spec.make_problem(instance)
-        eids = spec.ids_of(instance)
-        if self._tuner is not None:
-            # sessions created without an instance plan on first step
-            cfg = self._tuner.ensure_planned(problem.n_entities,
-                                             self.solve_cfg)
-            if cfg is not None:
-                self.solve_cfg = cfg
-        k = self.solve_cfg.k_for(problem.n_entities)
+        with obs.span("pop.prepare", cpu=True):
+            problem = spec.make_problem(instance)
+            eids = spec.ids_of(instance)
+            if self._tuner is not None:
+                # sessions created without an instance plan on first step
+                cfg = self._tuner.ensure_planned(problem.n_entities,
+                                                 self.solve_cfg)
+                if cfg is not None:
+                    self.solve_cfg = cfg
+            k = self.solve_cfg.k_for(problem.n_entities)
+            rkey = ("pop" if k > 1 else "full", spec.name, self.exec_cfg, k,
+                    problem.n_entities)
+            exec_run, rung = self._ladder(rkey, deadline_s, t0)
         if k > 1:
-            return self._step_pop(instance, problem, eids, k, deadline_s, t0)
-        return self._step_full(instance, problem, eids, deadline_s, t0)
+            return self._step_pop(instance, problem, eids, k, rkey,
+                                  exec_run, rung, t0)
+        return self._step_full(instance, problem, eids, rkey, exec_run,
+                               rung, t0)
 
-    def _step_pop(self, instance, problem, eids, k: int,
-                  deadline_s: Optional[float], t0: float) -> Allocation:
+    def _step_pop(self, instance, problem, eids, k: int, rkey: tuple,
+                  exec_run, rung: Optional[str], t0: float) -> Allocation:
         faults: list = []
         warm = self._warm if self._mode == "pop" else None
         if warm is not None and not _pop_warm_ok(warm):
@@ -693,8 +767,6 @@ class PopSession:
             self._warm, self._mode = None, None
             warm = None
         scfg = dataclasses.replace(self.solve_cfg, k=k)
-        rkey = ("pop", self.spec.name, self.exec_cfg, k, problem.n_entities)
-        exec_run, rung = self._ladder(rkey, deadline_s, t0)
         if rung == "fallback":
             return self._fallback(instance, faults + ["deadline"], t0,
                                   problem=problem)
@@ -765,8 +837,8 @@ class PopSession:
             iterations=int(np.asarray(res.iterations).sum()), raw=res,
             status=self._status_of(faults, rung), faults=tuple(faults))
 
-    def _step_full(self, instance, problem, eids,
-                   deadline_s: Optional[float], t0: float) -> Allocation:
+    def _step_full(self, instance, problem, eids, rkey: tuple, exec_run,
+                   rung: Optional[str], t0: float) -> Allocation:
         # ---- k=1: the unpartitioned full problem through the same substrate.
         # The flat LP has no per-entity remap, so warm only while the entity
         # identity sequence is unchanged (a same-size swap would silently
@@ -778,8 +850,6 @@ class PopSession:
         if warm is not None and (self._full_ids is None
                                  or ids_key != self._full_ids):
             warm = None
-        rkey = ("full", self.spec.name, self.exec_cfg, 1, problem.n_entities)
-        exec_run, rung = self._ladder(rkey, deadline_s, t0)
         if rung == "fallback":
             return self._fallback(instance, faults + ["deadline"], t0,
                                   problem=problem)
@@ -915,24 +985,26 @@ class PopSession:
         the domain's greedy hook.  Never returns non-finite data; raises
         only when there is literally nothing to serve."""
         spec = self.spec
-        alloc, source = None, None
-        if self.last is not None and _finite(self.last.alloc):
-            alloc, source = self.last.alloc, "previous-allocation"
-        elif spec.greedy is not None:
-            alloc, source = np.asarray(spec.greedy(instance)), "greedy"
-        if alloc is None:
-            raise RuntimeError(
-                f"tenant {self.tenant!r} ({spec.name}): cannot produce an "
-                f"allocation — solve failed ({', '.join(faults) or 'n/a'}) "
-                "and the session has no previous allocation and the domain "
-                "registers no greedy= fallback hook")
-        try:
-            metrics = dict(spec.metrics_of(instance, problem, alloc))
-        except Exception as e:
-            # fallback must not die computing metrics for an allocation
-            # that was never meant for this exact instance
-            metrics = {"metrics_error": f"{type(e).__name__}: {e}"}
-        metrics["fallback_source"] = source
+        with obs.span("pop.finish", cpu=True):
+            alloc, source = None, None
+            if self.last is not None and _finite(self.last.alloc):
+                alloc, source = self.last.alloc, "previous-allocation"
+            elif spec.greedy is not None:
+                alloc, source = np.asarray(spec.greedy(instance)), "greedy"
+            if alloc is None:
+                raise RuntimeError(
+                    f"tenant {self.tenant!r} ({spec.name}): cannot produce "
+                    f"an allocation — solve failed "
+                    f"({', '.join(faults) or 'n/a'}) and the session has no "
+                    "previous allocation and the domain registers no "
+                    "greedy= fallback hook")
+            try:
+                metrics = dict(spec.metrics_of(instance, problem, alloc))
+            except Exception as e:
+                # fallback must not die computing metrics for an allocation
+                # that was never meant for this exact instance
+                metrics = {"metrics_error": f"{type(e).__name__}: {e}"}
+            metrics["fallback_source"] = source
         # NOTE: no rounding hook here — a previous allocation is already
         # rounded, and greedy hooks return final allocations by contract
         return Allocation(
@@ -947,11 +1019,13 @@ class PopSession:
               kernels, plan_cache, k, warm_fraction, solve_time_s,
               build_time_s=0.0, iterations=0, raw=None, problem=None,
               status="ok", faults=()) -> Allocation:
-        alloc = raw_alloc
-        if self.spec.round is not None and self.spec.step_override is None:
-            alloc = self.spec.round(instance, raw_alloc)
-        if metrics is None:
-            metrics = self.spec.metrics_of(instance, problem, alloc)
+        with obs.span("pop.finish", cpu=True):
+            alloc = raw_alloc
+            if (self.spec.round is not None
+                    and self.spec.step_override is None):
+                alloc = self.spec.round(instance, raw_alloc)
+            if metrics is None:
+                metrics = self.spec.metrics_of(instance, problem, alloc)
         return Allocation(
             domain=self.spec.name, tenant=self.tenant, step=self.steps,
             alloc=alloc, metrics=metrics, backend=backend, engine=engine,
@@ -1170,29 +1244,48 @@ class PopService:
     # ------------------------------------------------------ solve funnels --
     def _solve_instance(self, problem, scfg, exec_cfg, *, warm,
                         entity_ids, **kw) -> "pop_mod.POPResult":
-        """Every session pop-path solve funnels through here: without a
-        dispatcher this IS the legacy call (same bytes, same jit keys);
-        with one, the pre/post stages run on the calling thread and only
-        the map-step launch goes through the dispatcher."""
-        if self.dispatcher is None:
-            return pop_mod.solve_instance(problem, scfg, exec_cfg,
-                                          warm=warm, entity_ids=entity_ids,
-                                          **kw)
-        prep = pop_mod.prepare_instance(problem, scfg, exec_cfg, warm=warm,
-                                        entity_ids=entity_ids, **kw)
-        res, solve_s = self.dispatcher.solve_prepared(
-            prep, problem.K_mv, problem.KT_mv)
-        return pop_mod.finish_prepared(prep, res, solve_s)
+        """Every session pop-path solve funnels through here, as
+        ``pop.solve_instance`` runs it: prepare, the map-step launch
+        (through the dispatcher when the service has one), finish — each
+        under its span."""
+        with obs.span("pop.prepare", cpu=True):
+            prep = pop_mod.prepare_instance(problem, scfg, exec_cfg,
+                                            warm=warm, entity_ids=entity_ids,
+                                            **kw)
+        with obs.span("pop.dispatch"):
+            res, solve_s = self._launch_prepared(prep)
+        with obs.span("pop.finish", cpu=True):
+            return pop_mod.finish_prepared(prep, res, solve_s)
 
     def _solve_full(self, problem, warm, exec_cfg) -> "pop_mod.FullResult":
         """The k=1 counterpart of :meth:`_solve_instance`."""
-        if self.dispatcher is None:
-            return pop_mod.solve_full_ex(problem, warm=warm,
-                                         exec_cfg=exec_cfg)
-        prep = pop_mod.prepare_full(problem, warm=warm, exec_cfg=exec_cfg)
-        res, solve_s = self.dispatcher.solve_prepared(
-            prep, problem.K_mv, problem.KT_mv)
-        return pop_mod.finish_full(prep, res, solve_s)
+        with obs.span("pop.prepare", cpu=True):
+            prep = pop_mod.prepare_full(problem, warm=warm,
+                                        exec_cfg=exec_cfg)
+        with obs.span("pop.dispatch"):
+            res, solve_s = self._launch_prepared(prep)
+        with obs.span("pop.finish", cpu=True):
+            return pop_mod.finish_full(prep, res, solve_s)
+
+    def _launch_prepared(self, prep) -> tuple:
+        """``(SolveResult, solve_time_s)`` of a prepared solve's map-step
+        launch: through the dispatcher, else inline as
+        ``pop.solve_instance`` runs it."""
+        problem = prep.problem
+        if self.dispatcher is not None:
+            return self.dispatcher.solve_prepared(prep, problem.K_mv,
+                                                  problem.KT_mv)
+        t1 = time.perf_counter()
+        with obs.span("pop.launch", cpu=True,
+                      lanes=backends_mod.batch_size(prep.ops), group=1,
+                      rids=(obs.current()[1],)):
+            res = backends_mod.solve_map(
+                prep.ops, problem.K_mv, problem.KT_mv, dict(prep.solver_kw),
+                backend=prep.backend, engine=prep.engine, warm=prep.warm,
+                **prep.opts)
+            with obs.span("pop.device"):
+                jax.block_until_ready(res.x)
+        return res, time.perf_counter() - t1
 
     def _submit(self, fn, *args, **kw) -> "concurrent.futures.Future":
         with self._lock:
