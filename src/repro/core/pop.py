@@ -18,7 +18,8 @@ stages:
                 map step needs ZERO collectives; this is the whole point of
                 POP).
   ``reduce()``  coalesce sub-allocations back to global entity order
-                (``core/reduce.py``).
+                (``core/reduce.py``), on the host: one read of the
+                result, the operator through :class:`HostLanes`.
 
 :func:`solve_instance` is the one-call wrapper chaining all four,
 configured by the frozen dataclasses in ``core/config.py``
@@ -127,7 +128,10 @@ class POPProblem:
     # --- solution handling -----------------------------------------------------
     def extract(self, op: OperatorLP, x: np.ndarray,
                 idx_row: np.ndarray) -> np.ndarray:
-        """Per-slot allocation rows [n_per, ...] from an LP solution."""
+        """Per-slot allocation rows [n_per, ...] from an LP solution.
+        ``x`` is the lane's numpy row; ``op`` is the lane's operator as
+        :meth:`HostLanes.lane` views, which move to the host only when
+        read."""
         raise NotImplementedError
 
     def evaluate(self, alloc: np.ndarray) -> dict:
@@ -166,6 +170,9 @@ class POPResult:
     # KKT score went non-finite or blew up; see pdhg.solve_stacked) — what
     # the service layer reads to cold-restart only the poisoned lanes
     diverged: Optional[np.ndarray] = None
+    # stacked operator leaves reduce moved to the host for ``extract``
+    # (the ``pop.finish`` span's ``op_reads``)
+    op_reads: int = 0
 
 
 # --------------------------------------------------------------------------
@@ -290,13 +297,80 @@ def solve(
 # stage 4: reduce
 # --------------------------------------------------------------------------
 
-def reduce(problem: POPProblem, pop_plan: PopPlan, ops: OperatorLP,
-           res: SolveResult) -> np.ndarray:
+class HostLanes:
+    """Per-lane host views of a stacked operator, for ``extract``.
+
+    :meth:`lane` has the operator's structure with every stacked leaf
+    replaced by a view of its lane.  A view's ``shape``, ``dtype`` and
+    ``ndim`` read no data; the first read of its values (``np.asarray``,
+    indexing, arithmetic) moves the whole stacked leaf to the host with
+    one ``jax.device_get``, for all k lanes.  A leaf that ``extract``
+    never reads stays on the device.  ``reads`` counts the leaves
+    moved."""
+
+    def __init__(self, stacked):
+        self._leaves, self._treedef = jax.tree.flatten(stacked)
+        self._host: dict = {}
+
+    @property
+    def reads(self) -> int:
+        return len(self._host)
+
+    def _leaf(self, j: int) -> np.ndarray:
+        if j not in self._host:
+            self._host[j] = jax.device_get(self._leaves[j])
+        return self._host[j]
+
+    def lane(self, i: int):
+        return self._treedef.unflatten(
+            [_LaneView(self, j, i) for j in range(len(self._leaves))])
+
+
+class _LaneView(np.lib.mixins.NDArrayOperatorsMixin):
+    """Lane ``i`` of stacked leaf ``j`` of a :class:`HostLanes`."""
+
+    __slots__ = ("_lanes", "_j", "_i", "shape", "dtype")
+
+    def __init__(self, lanes: HostLanes, j: int, i: int):
+        leaf = lanes._leaves[j]
+        self._lanes, self._j, self._i = lanes, j, i
+        self.shape = tuple(leaf.shape[1:])
+        self.dtype = np.dtype(leaf.dtype)
+
+    @property
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def _value(self) -> np.ndarray:
+        return self._lanes._leaf(self._j)[self._i]
+
+    def __array__(self, dtype=None, copy=None):
+        return np.array(self._value(), dtype=dtype, copy=copy)
+
+    def __array_ufunc__(self, ufunc, method, *inputs, **kw):
+        inputs = tuple(np.asarray(a) if isinstance(a, _LaneView) else a
+                       for a in inputs)
+        return getattr(ufunc, method)(*inputs, **kw)
+
+    def __getitem__(self, key):
+        return self._value()[key]
+
+
+def reduce(problem: POPProblem, pop_plan: PopPlan, ops, res: SolveResult
+           ) -> np.ndarray:
     """Coalesce per-lane allocations into the global one (scatter by entity
-    id; replicated entities SUM their replica sub-allocations)."""
+    id; replicated entities SUM their replica sub-allocations).  Runs on
+    the host and dispatches no device program: ``res.x`` is read once
+    (a no-op when it is already on the host), lanes are numpy rows, and
+    ``extract`` sees the stacked operator ``ops`` (or a
+    :class:`HostLanes` of it) through per-lane host views."""
+    lanes = ops if isinstance(ops, HostLanes) else HostLanes(ops)
+    x = jax.device_get(res.x)
     allocs = np.stack([
-        np.asarray(problem.extract(jax.tree.map(lambda a: a[i], ops),
-                                   np.asarray(res.x[i]), pop_plan.idx[i]))
+        np.asarray(problem.extract(lanes.lane(i), x[i], pop_plan.idx[i]))
         for i in range(pop_plan.k)
     ])
     if pop_plan.replication is None:
@@ -524,24 +598,31 @@ def prepare_instance(
 def finish_prepared(prep: PreparedSolve, res: SolveResult,
                     solve_time_s: float) -> POPResult:
     """Stage 4 for a :class:`PreparedSolve` whose launch already ran:
-    reduce per-lane allocations and assemble the :class:`POPResult`."""
+    reduce per-lane allocations and assemble the :class:`POPResult`.
+    Every field the result keeps comes to the host in one
+    ``jax.device_get``; from there on no device program runs."""
     p = prep.plan
-    alloc = reduce(prep.problem, p, prep.ops, res)
+    host = jax.device_get(dict(
+        x=res.x, y=res.y, iterations=res.iterations,
+        converged=res.converged, primal_obj=res.primal_obj,
+        diverged=res.diverged))
+    lanes = HostLanes(prep.ops)
+    alloc = reduce(prep.problem, p, lanes, res._replace(**host))
     return POPResult(
         alloc=alloc, idx=p.idx,
         solve_time_s=solve_time_s, build_time_s=prep.build_time_s,
-        iterations=np.asarray(res.iterations),
-        converged=np.asarray(res.converged),
+        iterations=host["iterations"],
+        converged=host["converged"],
         similarity=p.similarity or {},
-        sub_objectives=np.asarray(res.primal_obj),
+        sub_objectives=host["primal_obj"],
         replication=p.replication,
-        x=np.asarray(res.x), y=np.asarray(res.y),
+        x=host["x"], y=host["y"],
         plan=p, warm_stats=prep.warm_stats,
         backend=prep.backend, engine=pdhg.engine_name(prep.engine),
         kernels=pdhg.engine_kernels(prep.engine),
         plan_source=prep.plan_source,
-        diverged=(None if res.diverged is None
-                  else np.asarray(res.diverged)),
+        diverged=host["diverged"],
+        op_reads=lanes.reads,
     )
 
 

@@ -1254,8 +1254,10 @@ class PopService:
                                             **kw)
         with obs.span("pop.dispatch"):
             res, solve_s = self._launch_prepared(prep)
-        with obs.span("pop.finish", cpu=True):
-            return pop_mod.finish_prepared(prep, res, solve_s)
+        with obs.span("pop.finish", cpu=True) as sp:
+            out = pop_mod.finish_prepared(prep, res, solve_s)
+            sp.set(op_reads=out.op_reads)
+            return out
 
     def _solve_full(self, problem, warm, exec_cfg) -> "pop_mod.FullResult":
         """The k=1 counterpart of :meth:`_solve_instance`."""

@@ -7,16 +7,22 @@ solve_full objective at k>=4 while beating the greedy baseline."""
 
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import ExecConfig, SolveConfig, pop
+from repro.core.reduce import coalesce_concat, coalesce_replicated
 from repro.domains import (DomainSpec, GavelInstance, SpecProblem,
                            greedy_placement, make_placement_instance,
                            place_experts, register, registry)
 from repro.domains.moe_placement import SPEC as MOE_SPEC, _evaluate
 from repro.problems.cluster_scheduling import (GavelProblem,
                                                make_cluster_workload)
+from repro.problems.traffic_engineering import (TrafficProblem,
+                                                k_shortest_paths,
+                                                make_demands, make_topology)
 from repro.service import PopService
 
 KW = dict(max_iters=250, tol_primal=1e-4, tol_gap=1e-4)
@@ -80,6 +86,113 @@ def test_spec_problem_adapter_shares_matvec_identity():
     assert a.n_entities == 16 and b.n_entities == 24
     assert a.entity_attrs().shape == (16, 2)
     assert a.entity_scores().shape == (16,)
+
+
+# ---------------------------------------------------------------------------
+# finish: reduce on the host, one read of the result per step
+# ---------------------------------------------------------------------------
+
+def _small_instance(domain: str):
+    if domain == "moe_placement":
+        return make_placement_instance(32, 4, seed=0)
+    if domain == "gavel":
+        return GavelInstance(make_cluster_workload(24, seed=0))
+    topo = make_topology(20, 40, seed=0)
+    pairs, dem = make_demands(topo, 24, seed=0)
+    return TrafficProblem(topo, pairs, dem,
+                          k_shortest_paths(topo, pairs, n_paths=2,
+                                           max_len=10, seed=0))
+
+
+def _launched(domain: str):
+    """A prepared k=4 solve of a small instance and its launch's result,
+    still on the device."""
+    problem = registry.get(domain).make_problem(_small_instance(domain))
+    prep = pop.prepare_instance(problem,
+                                SolveConfig(k=4, strategy="stratified"),
+                                ExecConfig(solver_kw=KW))
+    res = pop.solve(problem, prep.plan, prep.ops, backend=prep.backend,
+                    engine=prep.engine, solver_kw=prep.solver_kw,
+                    backend_opts=prep.opts, warm=prep.warm)
+    return prep, res
+
+
+def _device_slice_reduce(prep, res) -> np.ndarray:
+    """Reduce as it read before: each lane of the operator and of
+    ``res.x`` sliced on the device and read back one by one."""
+    p = prep.plan
+    allocs = np.stack([
+        np.asarray(prep.problem.extract(
+            jax.tree.map(lambda a: a[i], prep.ops), np.asarray(res.x[i]),
+            p.idx[i]))
+        for i in range(p.k)])
+    if p.replication is None:
+        return coalesce_concat(allocs, p.idx, p.n_entities)
+    return coalesce_replicated(allocs, p.idx, p.replication)
+
+
+@pytest.mark.parametrize("domain", ["moe_placement", "traffic", "gavel"])
+def test_host_reduce_matches_device_slices(domain):
+    prep, res = _launched(domain)
+    out = pop.finish_prepared(prep, res, 0.0)
+    assert np.array_equal(out.alloc, _device_slice_reduce(prep, res))
+    assert np.array_equal(out.x, np.asarray(res.x))
+    assert np.array_equal(out.y, np.asarray(res.y))
+    assert np.array_equal(out.iterations, np.asarray(res.iterations))
+    # only the leaves extract reads move, each once for all lanes
+    assert out.op_reads < len(jax.tree.leaves(prep.ops))
+
+
+def test_finish_dispatches_no_device_program(monkeypatch):
+    """A MoE step's finish runs no eager device program and reads the
+    result only through explicit ``jax.device_get``."""
+    from jax._src import core as jax_core
+    prep, res = _launched("moe_placement")
+    jax.block_until_ready(res)
+    dispatched = []
+    orig = jax_core.EvalTrace.process_primitive
+
+    def counted(self, prim, tracers, params):
+        dispatched.append(prim.name)
+        return orig(self, prim, tracers, params)
+    monkeypatch.setattr(jax_core.EvalTrace, "process_primitive", counted)
+
+    _device_slice_reduce(prep, res)
+    assert dispatched, "the counter sees eager device slices"
+    dispatched.clear()
+    with jax.transfer_guard_device_to_host("disallow"):
+        out = pop.finish_prepared(prep, res, 0.0)
+    assert dispatched == []
+    assert out.op_reads == 0
+
+
+def test_host_lanes_move_a_leaf_once_when_read():
+    prep, _ = _launched("gavel")
+    lanes = pop.HostLanes(prep.ops)
+    views = [lanes.lane(i) for i in range(prep.plan.k)]
+    S = prep.ops.data[0]
+    assert views[1].data[0].shape == S.shape[1:]
+    assert views[1].data[0].dtype == S.dtype
+    assert lanes.reads == 0
+    for i, v in enumerate(views):
+        assert np.array_equal(np.asarray(v.data[0]), np.asarray(S[i]))
+        assert np.array_equal(v.data[0] * 2, 2 * np.asarray(S[i]))
+    assert lanes.reads == 1
+
+
+@pytest.mark.parametrize("domain", ["moe_placement", "gavel"])
+def test_finish_span_counts_operator_reads(domain):
+    """MoE's extract reads no operator leaf; Gavel's reads some of them,
+    each moved once for all lanes."""
+    inst = _small_instance(domain)
+    sess = PopService().session(
+        "t", inst, solve=SolveConfig(k=4, strategy="stratified"),
+        exec=ExecConfig(solver_kw=KW))
+    sess.step(inst)
+    finish = [r for r in obs.spans()[0]
+              if r.name == "pop.finish" and "op_reads" in r.attrs][-1]
+    reads = finish.attrs["op_reads"]
+    assert reads == 0 if domain == "moe_placement" else reads > 0
 
 
 # ---------------------------------------------------------------------------
